@@ -30,6 +30,7 @@ def _run_pp_cell(arch: str, timeout: float = 2400.0) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # fake host devices; never the chip
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun_pp", arch, str(out)],
         capture_output=True, text=True, timeout=timeout, env=env)

@@ -108,7 +108,6 @@ def _jx() -> SimpleNamespace:
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.kernels.minplus import minplus_matmul
 
@@ -189,7 +188,8 @@ def _jx() -> SimpleNamespace:
                                    (sfill[1:], ssmax[1:], valid[1:]))
         return dp, choices
 
-    return SimpleNamespace(jax=jax, jnp=jnp, x64=enable_x64,
+    return SimpleNamespace(jax=jax, jnp=jnp,
+                           x64=functools.partial(jax.enable_x64, True),
                            dfts_scan=dfts_scan, kseq_scan=kseq_scan,
                            kseq_pipe_scan=kseq_pipe_scan)
 

@@ -13,7 +13,8 @@ the +inf-padded, NaN-free cost matrices the solvers produce).  +inf is the
 semiring zero: padded rows/columns are absorbing and can never win a min
 against a finite entry, which is what makes shape padding safe.
 
-Validated in interpret mode on CPU (the CI path); Mosaic lowering on TPU.
+Validated in interpret mode on CPU (the CI path); on TPU it compiles through
+Mosaic in float32 (tests/test_tpu_compile.py).
 The jnp oracle is :func:`repro.kernels.ref.reference_minplus`.
 """
 from __future__ import annotations
@@ -34,13 +35,16 @@ _BN = 128
 
 def _minplus_kernel(a_ref, b_ref, val_ref, idx_ref):
     a = a_ref[0]  # (M, K)
-    b = b_ref[0]  # (K, N)
     m, k = a.shape
-    n = b.shape[1]
+    n = b_ref.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (m, k), 1)
 
     def body(j, carry):
         val, idx = carry
-        cand = a[:, j][:, None] + b[j, :][None, :]  # (M, N)
+        # Column j of `a` as a masked lane min: Mosaic lowers no dynamic lane
+        # slice, and min over +inf fill returns a[:, j] exactly.
+        col = jnp.min(jnp.where(lane == j, a, jnp.inf), axis=1, keepdims=True)
+        cand = col + b_ref[0, pl.ds(j, 1), :]  # (M, 1) + (1, N)
         better = cand < val  # strict: first minimum wins (argmin convention)
         return (jnp.where(better, cand, val),
                 jnp.where(better, j, idx))
@@ -71,6 +75,10 @@ def minplus_matmul(a, b, *, interpret: bool | None = None):
     whole column is +inf, matching ``jnp.argmin``).  Inputs are padded with
     +inf to TPU tile multiples and the padding is sliced back off, so any
     shapes (including non-tile-multiples) are accepted.
+
+    ``interpret`` defaults to the backend: the Pallas interpreter on CPU,
+    Mosaic otherwise.  Mosaic has no float64, so a compiled call with f64
+    operands raises ``TypeError``.
     """
     if a.ndim != b.ndim or a.shape[:-2] != b.shape[:-2]:
         raise ValueError(f"batch dims must match, got {a.shape} vs {b.shape}")
@@ -86,6 +94,10 @@ def minplus_matmul(a, b, *, interpret: bool | None = None):
     Np = b3.shape[-1]
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
+    if not interpret and jnp.float64 in (a.dtype, b.dtype):
+        raise TypeError("minplus_matmul: Mosaic has no float64; compile the "
+                        "kernel with float32 operands or run it with "
+                        "interpret=True")
     val, idx = pl.pallas_call(
         _minplus_kernel,
         grid=(B,),

@@ -70,8 +70,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str) -> dict:
     mem = compiled.memory_analysis()
     print(f"[{arch}|{shape_name}|{mesh_name}] memory_analysis:", mem)
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x returns [dict], >=0.6 dict
-        cost = cost[0] if cost else {}
     builtin_flops = float(cost.get("flops", 0.0))
     builtin_bytes = float(cost.get("bytes accessed", 0.0))
     print(f"[{arch}|{shape_name}|{mesh_name}] cost_analysis (builtin, "
@@ -153,6 +151,7 @@ def run_all(force: bool = False, quick: bool = False,
         env = dict(os.environ)
         src = str(Path(__file__).resolve().parents[2])
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        env["JAX_PLATFORMS"] = "cpu"  # fake host devices; never the chip
         proc = subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun", "--cell", arch,
              shape, mesh],

@@ -3,6 +3,7 @@ importing this module does not touch jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -10,11 +11,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods x 256 chips as ('pod','data','model') = (2,16,16)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 # v5e hardware constants (roofline denominators; see EXPERIMENTS.md §Roofline)

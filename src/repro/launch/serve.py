@@ -24,7 +24,9 @@ def main() -> None:
     from ..configs import get_config
     from ..models import transformer as T
     from ..serving import ServingEngine
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

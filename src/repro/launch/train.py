@@ -1,8 +1,8 @@
 """Training launcher: --arch <id> on the local device mesh, with planner-driven
 pipeline mode, checkpointing, elastic re-planning hooks, and the synthetic data
-pipeline.  On this CPU container it trains reduced configs end-to-end; on a real
-TPU slice the same entrypoint scales to the production meshes (mesh shape is
-taken from the available device count).
+pipeline.  On CPU it trains reduced configs end-to-end; on a TPU, ``--full``
+trains at published widths.  ``--mode msl-pp`` runs one pipeline stage per
+device: one stage on one chip, the planner's K = device-count chain on more.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --steps 50 \
       [--mode dp|msl-pp] [--reduced] [--ckpt-dir DIR] [--resume]
@@ -38,7 +38,9 @@ def main() -> None:
     from ..models import transformer as T
     from ..optim import make_optimizer
     from ..train import make_train_step
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -47,23 +49,18 @@ def main() -> None:
     opt_state = opt.init(params)
 
     if args.mode == "msl-pp":
-        from ..msl import make_pipeline_mesh, make_pipeline_train_step
-        from ..msl.planner import PipelinePlan
+        from ..msl import make_pipeline_train_step, plan_on_devices
 
-        n_dev = jax.device_count()
-        K = 2 if n_dev >= 4 else 1
-        if K < 2:
-            raise SystemExit("msl-pp needs >= 4 devices "
-                             "(set XLA_FLAGS=--xla_force_host_platform_device_count=4)")
-        R = cfg.n_layers // len(cfg.pattern)
-        plan = PipelinePlan(K=2, segments=[(1, R // 2), (R // 2 + 1, R)],
-                            placement=["s0", "s1"], n_groups=R,
-                            predicted_latency_s=0.0, breakdown={})
-        mesh = make_pipeline_mesh(2, n_dev // 2)
-        step_fn = jax.jit(make_pipeline_train_step(cfg, mesh, plan,
-                                                   args.n_micro, opt))
+        # one pipeline stage per device: the whole chain on one chip, the
+        # planner's K = device-count chain otherwise
+        plan, mesh = plan_on_devices(cfg, jax.device_count(),
+                                     seq_len=args.seq,
+                                     microbatch=args.batch // args.n_micro)
+        print(f"[msl-pp] K={plan.K} segments={plan.segments}")
+        step_fn = make_pipeline_train_step(cfg, mesh, plan, args.n_micro, opt)
     else:
-        step_fn = jax.jit(make_train_step(cfg, opt))
+        step_fn = make_train_step(cfg, opt)
+    step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
 
     ckpt = CheckpointManager(args.ckpt_dir or f"/tmp/repro_{args.arch}_ckpt")
     start = 0

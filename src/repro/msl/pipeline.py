@@ -19,38 +19,32 @@ segment carry a False validity flag and pass the residual through unchanged.
 """
 from __future__ import annotations
 
-import inspect
 from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from ..configs.base import ModelConfig
 from ..models import layers as L
 from ..models import transformer as T
 from ..models.layers import Ctx
 from ..train.steps import chunked_xent
-from .planner import PipelinePlan
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# The replication-check kwarg was renamed across JAX versions (0.4.x:
-# `check_rep`, >= 0.6: `check_vma`); detect whichever this JAX accepts so the
-# pipeline disables it on either line (and passes nothing if both are gone).
-_CHECK_KWARGS = (
-    {kw: False}
-    for kw in ("check_vma", "check_rep")
-    if kw in inspect.signature(shard_map).parameters
-)
-SHARD_MAP_CHECK_KWARGS: dict = next(_CHECK_KWARGS, {})
-
+from .planner import PipelinePlan, plan_pipeline
 
 def make_pipeline_mesh(n_stages: int, n_data: int) -> Mesh:
-    return jax.make_mesh((n_stages, n_data), ("stage", "data"))
+    return jax.make_mesh((n_stages, n_data), ("stage", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
+
+
+def plan_on_devices(cfg: ModelConfig, n_stages: int, *, seq_len: int,
+                    microbatch: int) -> tuple[PipelinePlan, Mesh]:
+    """One pipeline stage per device: the planner's K = `n_stages` chain and
+    its (n_stages, 1) mesh.  On one device that is a single stage holding
+    every group."""
+    plan = plan_pipeline(cfg, seq_len=seq_len, microbatch=microbatch,
+                         candidate_K=(n_stages,))
+    return plan, make_pipeline_mesh(plan.K, 1)
 
 
 # ------------------------------------------------------------ param restacking
@@ -159,14 +153,14 @@ def pipeline_forward(params, batch, cfg: ModelConfig, mesh: Mesh,
     x = T.embed_tokens(params, cfg, tokens)
     h_mb = x.reshape(n_micro, mb, S, -1)
     groups_stacked, valid = stack_for_pipeline(params, cfg, plan)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(pipelined_apply, cfg=cfg, K=plan.K, n_micro=n_micro),
         mesh=mesh,
         in_specs=(tuple(jax.tree.map(lambda _: P("stage"), g)
                         for g in groups_stacked), P("stage"),
                   P(None, "data")),
         out_specs=(P("stage", "data"), P("stage")),
-        **SHARD_MAP_CHECK_KWARGS,
+        check_vma=False,
     )
     outs, aux = fn(groups_stacked, valid, h_mb)
     # out dim0 is stage-major (K * M); the last stage's block holds the model

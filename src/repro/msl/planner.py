@@ -75,10 +75,11 @@ def plan_pipeline(cfg: ModelConfig, *, seq_len: int, microbatch: int,
     for K in candidate_K:
         if K > prof.L or K > len(nodes):
             continue
-        cands = [[nodes[0]]] + [nodes[1:-1] or nodes for _ in range(K - 2)] \
-            + [[nodes[-1]]]
-        if K == 1:
-            continue
+        if K == 1:  # one stage, on the source group
+            cands = [[nodes[0]]]
+        else:
+            cands = [[nodes[0]]] + [nodes[1:-1] or nodes
+                                    for _ in range(K - 2)] + [[nodes[-1]]]
         req = ServiceChainRequest(cfg.name, nodes[0], nodes[-1], microbatch,
                                   mode)
         res = solve(ProblemInstance(net, prof, req, K,

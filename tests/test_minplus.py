@@ -14,7 +14,6 @@ import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental import enable_x64  # noqa: E402
 
 from repro.kernels.minplus import minplus_matmul  # noqa: E402
 from repro.kernels.ref import reference_minplus  # noqa: E402
@@ -24,12 +23,12 @@ INF = np.inf
 
 def _mm(a, b):
     """Kernel under f64 (the solvers always call it inside ``enable_x64``)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         return minplus_matmul(jnp.asarray(a), jnp.asarray(b), interpret=True)
 
 
 def _ref(a, b):
-    with enable_x64():
+    with jax.enable_x64(True):
         return reference_minplus(jnp.asarray(a), jnp.asarray(b))
 
 
@@ -121,6 +120,16 @@ def test_shape_errors():
         _mm(np.zeros((2, 3)), np.zeros((4, 2)))
     with pytest.raises(ValueError, match="batch"):
         _mm(np.zeros((2, 2, 3)), np.zeros((3, 3, 2)))
+
+
+def test_compiled_f64_raises():
+    """Mosaic has no float64: a compiled (non-interpret) f64 call must say so
+    rather than fall back to the interpreter or the jnp reference."""
+    with jax.enable_x64(True):
+        a = jnp.zeros((2, 3), jnp.float64)
+        b = jnp.zeros((3, 2), jnp.float64)
+        with pytest.raises(TypeError, match="float64"):
+            minplus_matmul(a, b, interpret=False)
 
 
 # ------------------------------------------------------ hypothesis fuzzing

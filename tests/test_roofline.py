@@ -14,10 +14,10 @@ TOY = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.roofline.hlo_cost import analyze_hlo
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
 
 def step(w, x):
     def body(h, wi):

@@ -1,0 +1,111 @@
+"""Compile-only checks of the device paths for a described TPU v5e (2x2).
+
+Nothing runs on a chip here: each test lowers a jitted program for one
+described v5e device and compiles it with the TPU compiler, which refuses
+what the chip would refuse (unlowerable Pallas primitives, float64 in
+Mosaic, programs over the device's memory).  The topology is described
+inside a module fixture, never at import, so every pytest-xdist worker
+collects the same tests and only the one running this file loads libtpu.
+"""
+from __future__ import annotations
+
+import os
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler or topology support here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device compile can be written to the persistent cache but
+    # never read back without the chip; keep it out of the cache
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# Solver shapes: N=64 instances, K=4 stages, S=16 candidates (the min-plus
+# kernel's (64,1,16)x(64,16,16) call inside dfts_scan); ResNet101's L=37
+# layers give (L+1)=38 segmentation grids, T=64 padded bottleneck caps.
+N, K, S, LP1, T = 64, 4, 16, 38, 64
+
+
+def _scan_args(name, sh):
+    f64 = jnp.float64
+    if name == "dfts_scan":
+        return (_spec((N, K, S), f64, sh), _spec((N, K - 1, S, S), f64, sh),
+                _spec((N, S), f64, sh))
+    if name == "kseq_scan":
+        return (_spec((K, LP1, LP1), f64, sh), _spec((K, LP1), jnp.bool_, sh))
+    return (_spec((K, LP1, LP1), f64, sh), _spec((K, LP1, LP1), f64, sh),
+            _spec((K, LP1), jnp.bool_, sh), _spec((T,), f64, sh))
+
+
+@pytest.mark.parametrize("name", ["dfts_scan", "kseq_scan", "kseq_pipe_scan"])
+def test_solver_scan_compiles_f64(one_chip, name):
+    from repro.core.jax_solvers import _jx
+
+    fn = getattr(_jx(), name)
+    with jax.enable_x64(True):
+        compiled = fn.lower(*_scan_args(name, one_chip)).compile()
+    assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("a_shape,b_shape", [
+    ((64, 1, 16), (64, 16, 16)),  # the dfts_scan call
+    ((2, 9, 130), (2, 130, 3)),   # crosses the (8, 128) tile on M and K
+])
+def test_minplus_compiles_f32_through_mosaic(one_chip, a_shape, b_shape):
+    from repro.kernels.minplus import minplus_matmul
+
+    compiled = minplus_matmul.lower(
+        _spec(a_shape, jnp.float32, one_chip),
+        _spec(b_shape, jnp.float32, one_chip), interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_stage_apply_group_compiles_full_width(one_chip):
+    """Forward and backward of one mamba2-370m group at published width, as
+    a pipeline stage runs it (microbatch 4, seq 2048)."""
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    from repro.models.layers import Ctx
+    from repro.msl.pipeline import _stage_apply
+
+    cfg = get_config("mamba2-370m")
+    params = jax.eval_shape(lambda k: T.init_params(k, cfg),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    group = tuple(
+        jax.tree.map(lambda p: _spec((1,) + p.shape[1:], p.dtype, one_chip),
+                     g) for g in params["stack"]["groups"])
+    mb, seq = 4, 2048
+    x = _spec((mb, seq, cfg.d_model), jnp.bfloat16, one_chip)
+
+    def loss(g, x):
+        pos = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32), (mb, seq))
+        h, aux = _stage_apply(g, jnp.ones((1,), bool), cfg, x,
+                              Ctx(mode="train", positions=pos))
+        return jnp.sum(h.astype(jnp.float32)) + aux
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        group, x).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 * 1024**3
