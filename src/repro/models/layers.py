@@ -609,37 +609,45 @@ def ssd_block(p, cfg: ModelConfig, x, ctx: Ctx, cache):
     H = Di // cfg.ssm_head_dim
     P = cfg.ssm_head_dim
     N = cfg.ssm_state
-    zxbcdt = x @ p["w_in"].astype(dt_)
-    z, xs, Bm, Cm, dtv = jnp.split(
-        zxbcdt, [Di, 2 * Di, 2 * Di + N, 2 * Di + 2 * N], axis=-1)
-    conv_in = jnp.concatenate([xs, Bm, Cm], axis=-1)
-    conv_state = cache.get("conv") if cache else None
-    conv_out, new_conv = _causal_depthwise_conv(conv_in, p["conv_w"].astype(dt_),
-                                                conv_state)
-    conv_out = jax.nn.silu(conv_out)
-    xs, Bm, Cm = jnp.split(conv_out, [Di, Di + N], axis=-1)
-    xh = xs.reshape(B, S, H, P).astype(jnp.float32)
-    dtv = jax.nn.softplus(dtv.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
-    A = jnp.exp(p["A_log"])  # (H,) positive rates
-    Bm32, Cm32 = Bm.astype(jnp.float32), Cm.astype(jnp.float32)
-
-    if ctx.decoding and cache is not None:
-        h0 = cache["h"]  # (B, H, P, N)
-        dA = jnp.exp(-dtv[:, 0] * A[None, :])  # (B, H)
-        dBx = jnp.einsum("bh,bn,bhp->bhpn", dtv[:, 0], Bm32[:, 0], xh[:, 0])
-        h = h0 * dA[:, :, None, None] + dBx
-        y = jnp.einsum("bn,bhpn->bhp", Cm32[:, 0], h)[:, None]
-        new_cache = {"h": h, "conv": new_conv}
-    else:
-        h0 = cache["h"] if (cache is not None and "h" in cache) else None
-        # NOTE: A enters negated inside `_ssd_chunked` via dA = dt*A with decay
-        # exp(-(cum_t - cum_s)); we pass positive rates and negate there.
-        y, h_last = _ssd_chunked(xh, dtv, -A, Bm32, Cm32, h0, cfg.ssm_chunk)
-        new_cache = {"h": h_last, "conv": new_conv} if cache is not None else None
-    y = y + p["D_skip"][None, None, :, None] * xh
-    y = y.reshape(B, S, Di).astype(dt_)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
-    return y @ p["w_out"].astype(dt_), new_cache
+    with jax.named_scope("ssd_in_proj"):
+        zxbcdt = x @ p["w_in"].astype(dt_)
+        z, xs, Bm, Cm, dtv = jnp.split(
+            zxbcdt, [Di, 2 * Di, 2 * Di + N, 2 * Di + 2 * N], axis=-1)
+    with jax.named_scope("ssd_conv"):
+        conv_in = jnp.concatenate([xs, Bm, Cm], axis=-1)
+        conv_state = cache.get("conv") if cache else None
+        conv_out, new_conv = _causal_depthwise_conv(
+            conv_in, p["conv_w"].astype(dt_), conv_state)
+        conv_out = jax.nn.silu(conv_out)
+        xs, Bm, Cm = jnp.split(conv_out, [Di, Di + N], axis=-1)
+    with jax.named_scope("ssd_scan"):
+        xh = xs.reshape(B, S, H, P).astype(jnp.float32)
+        dtv = jax.nn.softplus(dtv.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
+        A = jnp.exp(p["A_log"])  # (H,) positive rates
+        Bm32, Cm32 = Bm.astype(jnp.float32), Cm.astype(jnp.float32)
+        if ctx.decoding and cache is not None:
+            h0 = cache["h"]  # (B, H, P, N)
+            dA = jnp.exp(-dtv[:, 0] * A[None, :])  # (B, H)
+            dBx = jnp.einsum("bh,bn,bhp->bhpn", dtv[:, 0], Bm32[:, 0],
+                             xh[:, 0])
+            h = h0 * dA[:, :, None, None] + dBx
+            y = jnp.einsum("bn,bhpn->bhp", Cm32[:, 0], h)[:, None]
+            new_cache = {"h": h, "conv": new_conv}
+        else:
+            h0 = cache["h"] if (cache is not None and "h" in cache) else None
+            # NOTE: A enters negated inside `_ssd_chunked` via dA = dt*A with
+            # decay exp(-(cum_t - cum_s)); we pass positive rates and negate
+            # there.
+            y, h_last = _ssd_chunked(xh, dtv, -A, Bm32, Cm32, h0,
+                                     cfg.ssm_chunk)
+            new_cache = ({"h": h_last, "conv": new_conv} if cache is not None
+                         else None)
+    with jax.named_scope("ssd_gate_norm"):
+        y = y + p["D_skip"][None, None, :, None] * xh
+        y = y.reshape(B, S, Di).astype(dt_)
+        y = rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+    with jax.named_scope("ssd_out_proj"):
+        return y @ p["w_out"].astype(dt_), new_cache
 
 
 def init_ssd_cache(cfg: ModelConfig, batch: int):

@@ -83,43 +83,49 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, ctx: Ctx, cache):
     activations per layer (§Perf, hillclimb #1)."""
 
     def norm_in(scale_name: str):
-        return _sp_gather(L.rmsnorm(x, p[scale_name], cfg.norm_eps))
+        with jax.named_scope("block_norm"):
+            return _sp_gather(L.rmsnorm(x, p[scale_name], cfg.norm_eps))
+
+    def sub(scope: str, fn, *args, **kw):
+        with jax.named_scope(scope):
+            return fn(*args, **kw)
 
     aux = jnp.zeros((), jnp.float32)
     if kind in ("attn", "local_attn", "moe", "moe_dense"):
         window = cfg.window if kind == "local_attn" else None
-        h, cache = L.attention_block(p["attn"], cfg, norm_in("ln1"),
-                                     ctx, cache, window=window)
+        h, cache = sub("attn", L.attention_block, p["attn"], cfg,
+                       norm_in("ln1"), ctx, cache, window=window)
         x = x + _sp_scatter(h)
         hin = norm_in("ln2")
         if kind in ("moe", "moe_dense"):
-            y, aux = L.moe_ffn(p["moe"], cfg, hin)
+            y, aux = sub("moe", L.moe_ffn, p["moe"], cfg, hin)
             if kind == "moe_dense":
-                y = y + L.mlp(p["mlp"], cfg, hin)
+                y = y + sub("mlp", L.mlp, p["mlp"], cfg, hin)
         else:
-            y = L.mlp(p["mlp"], cfg, hin)
+            y = sub("mlp", L.mlp, p["mlp"], cfg, hin)
         x = x + _sp_scatter(y)
     elif kind == "xattn":
-        h, cache = L.attention_block(p["attn"], cfg, norm_in("ln1"),
-                                     ctx, cache, cross=True)
+        h, cache = sub("attn", L.attention_block, p["attn"], cfg,
+                       norm_in("ln1"), ctx, cache, cross=True)
         x = x + _sp_scatter(h)
-        x = x + _sp_scatter(L.mlp(p["mlp"], cfg, norm_in("ln2")))
+        x = x + _sp_scatter(sub("mlp", L.mlp, p["mlp"], cfg, norm_in("ln2")))
     elif kind == "dec_block":
         c_self = cache["self"] if cache else None
         c_cross = cache["cross"] if cache else None
-        h, c_self = L.attention_block(p["attn"], cfg, norm_in("ln1"),
-                                      ctx, c_self)
+        h, c_self = sub("attn", L.attention_block, p["attn"], cfg,
+                        norm_in("ln1"), ctx, c_self)
         x = x + _sp_scatter(h)
-        h, c_cross = L.attention_block(p["xattn"], cfg, norm_in("ln2"),
-                                       ctx, c_cross, cross=True)
+        h, c_cross = sub("attn", L.attention_block, p["xattn"], cfg,
+                         norm_in("ln2"), ctx, c_cross, cross=True)
         x = x + _sp_scatter(h)
-        x = x + _sp_scatter(L.mlp(p["mlp"], cfg, norm_in("ln3")))
+        x = x + _sp_scatter(sub("mlp", L.mlp, p["mlp"], cfg, norm_in("ln3")))
         cache = ({"self": c_self, "cross": c_cross} if cache is not None
                  else None)
     elif kind == "rglru":
-        h, cache = L.rglru_block(p["rglru"], cfg, norm_in("ln1"), ctx, cache)
+        h, cache = sub("rglru", L.rglru_block, p["rglru"], cfg,
+                       norm_in("ln1"), ctx, cache)
         x = x + _sp_scatter(h)
-        x = x + _sp_scatter(L.mlp(p["mlp"], cfg, norm_in("ln2")))
+        x = x + _sp_scatter(sub("mlp", L.mlp, p["mlp"], cfg, norm_in("ln2")))
     elif kind == "ssd":
         h, cache = L.ssd_block(p["ssd"], cfg, norm_in("ln1"), ctx, cache)
         x = x + _sp_scatter(h)

@@ -16,6 +16,11 @@ the LM head run outside the pipeline region, sharded over 'data' (DESIGN.md).
 Stages run one structurally identical program: every stage scans over
 `Gmax = ceil(n_groups / K)` group slots; slots beyond the stage's planner
 segment carry a False validity flag and pass the residual through unchanged.
+
+Each component of the train step runs under a flat `jax.named_scope` (`embed`,
+`restack`, `tick`, `stage`, `bubble`, `ppermute`, `head`, `optimizer`, and the
+block scopes of models/), which a profiler trace reads back per scope
+(docs/pipeline.md, "Reading a device trace of the train step").
 """
 from __future__ import annotations
 
@@ -112,6 +117,14 @@ def pipelined_apply(groups_stacked, valid, h_mb, *, cfg: ModelConfig, K: int,
     my_groups = tuple(jax.tree.map(lambda p: p[0], g) for g in groups_stacked)
     my_valid = valid[0]
 
+    def stage_fn(xi):
+        with jax.named_scope("stage"):
+            return _stage_apply(my_groups, my_valid, cfg, xi, ctx)
+
+    def bubble_fn(xi):
+        with jax.named_scope("bubble"):
+            return xi, jnp.zeros((), jnp.float32)
+
     def tick(carry, t):
         received, outs, aux_acc = carry
         inject = h_mb[jnp.clip(t, 0, M - 1)]
@@ -120,11 +133,7 @@ def pipelined_apply(groups_stacked, valid, h_mb, *, cfg: ModelConfig, K: int,
         # lax.cond (real XLA conditional — not vmapped into a select here)
         # skips the fill/drain garbage compute entirely
         active = (t >= stage) & (t - stage < M)
-        y, aux = jax.lax.cond(
-            active,
-            lambda xi: _stage_apply(my_groups, my_valid, cfg, xi, ctx),
-            lambda xi: (xi, jnp.zeros((), jnp.float32)),
-            x_in)
+        y, aux = jax.lax.cond(active, stage_fn, bubble_fn, x_in)
         # the last stage collects microbatch t - (K - 1)
         oidx = jnp.clip(t - (K - 1), 0, M - 1)
         take = t >= K - 1
@@ -132,14 +141,16 @@ def pipelined_apply(groups_stacked, valid, h_mb, *, cfg: ModelConfig, K: int,
             outs, jnp.where(take, y, outs[oidx]), oidx, 0)
         # ship smashed data along the chain (ring permute; the wrap-around
         # edge K-1 -> 0 is ignored by stage 0's inject select)
-        nxt = jax.lax.ppermute(y, "stage",
-                               [(i, (i + 1) % K) for i in range(K)])
+        with jax.named_scope("ppermute"):
+            nxt = jax.lax.ppermute(y, "stage",
+                                   [(i, (i + 1) % K) for i in range(K)])
         return (nxt, outs, aux_acc + aux), None
 
-    (_, outs, aux), _ = jax.lax.scan(
-        tick, (jnp.zeros_like(h_mb[0]), jnp.zeros_like(h_mb),
-               jnp.zeros((), jnp.float32)),
-        jnp.arange(n_ticks))
+    with jax.named_scope("tick"):
+        (_, outs, aux), _ = jax.lax.scan(
+            tick, (jnp.zeros_like(h_mb[0]), jnp.zeros_like(h_mb),
+                   jnp.zeros((), jnp.float32)),
+            jnp.arange(n_ticks))
     return outs, aux[None]
 
 
@@ -150,9 +161,11 @@ def pipeline_forward(params, batch, cfg: ModelConfig, mesh: Mesh,
     B, S = tokens.shape
     assert B % n_micro == 0
     mb = B // n_micro
-    x = T.embed_tokens(params, cfg, tokens)
+    with jax.named_scope("embed"):
+        x = T.embed_tokens(params, cfg, tokens)
     h_mb = x.reshape(n_micro, mb, S, -1)
-    groups_stacked, valid = stack_for_pipeline(params, cfg, plan)
+    with jax.named_scope("restack"):
+        groups_stacked, valid = stack_for_pipeline(params, cfg, plan)
     fn = jax.shard_map(
         partial(pipelined_apply, cfg=cfg, K=plan.K, n_micro=n_micro),
         mesh=mesh,
@@ -165,9 +178,10 @@ def pipeline_forward(params, batch, cfg: ModelConfig, mesh: Mesh,
     outs, aux = fn(groups_stacked, valid, h_mb)
     # out dim0 is stage-major (K * M); the last stage's block holds the model
     # output microbatches
-    h_last = outs[-n_micro:]
-    hidden = h_last.reshape(B, S, -1)
-    hidden = L.rmsnorm(hidden, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        h_last = outs[-n_micro:]
+        hidden = h_last.reshape(B, S, -1)
+        hidden = L.rmsnorm(hidden, params["final_norm"], cfg.norm_eps)
     # aux averaged over ticks (bubble ticks process pass-through garbage; the
     # valid-slot masking keeps their contribution bounded)
     return hidden, jnp.sum(aux) / (n_micro + plan.K - 1)
@@ -177,14 +191,16 @@ def make_pipeline_train_step(cfg: ModelConfig, mesh: Mesh, plan: PipelinePlan,
                              n_micro: int, opt):
     def loss_fn(params, batch):
         hidden, aux = pipeline_forward(params, batch, cfg, mesh, plan, n_micro)
-        head_w = T.head_matrix(params, cfg).astype(hidden.dtype)
-        nll = chunked_xent(hidden, head_w, batch["targets"], cfg)
+        with jax.named_scope("head"):
+            head_w = T.head_matrix(params, cfg).astype(hidden.dtype)
+            nll = chunked_xent(hidden, head_w, batch["targets"], cfg)
         return nll + 0.01 * aux, nll
 
     def train_step(params, opt_state, batch):
         (loss, nll), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, batch)
-        params, opt_state = opt.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt.update(grads, opt_state, params)
         return params, opt_state, {"loss": loss, "nll": nll}
 
     return train_step
