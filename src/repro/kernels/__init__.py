@@ -1,17 +1,20 @@
 """Pallas TPU kernels for the substrate's compute hot spots (the paper itself
 has no kernel-level contribution — see DESIGN.md Sec. 2.3): flash attention,
-per-expert grouped matmul, RG-LRU recurrence, Mamba-2 SSD intra-chunk.
+per-expert grouped matmul, RG-LRU recurrence, min-plus matmul, and the
+Mamba-2 SSD scan.
 
 Each kernel: <name>.py (pl.pallas_call + explicit BlockSpec VMEM tiling),
-ops.py (jit'd wrappers), ref.py (pure-jnp oracles).  Validated in interpret
-mode on CPU; Mosaic lowering on real TPUs.
+ops.py (wrappers), ref.py (pure-jnp oracles).  Validated in interpret mode on
+CPU; Mosaic lowering on real TPUs.  The SSD scan's forward and backward
+kernels (ssd.py) are the train step's SSD path on a TPU where the shapes tile
+(``models.layers`` chooses by the platform lowered for and by shape); its
+oracle, and the path everywhere else, is ``models.layers._ssd_chunked``.
 """
 from . import ops, ref
 from .flash_attention import flash_attention
 from .minplus import minplus_matmul
 from .moe_gmm import expert_matmul
 from .rglru import rglru_scan
-from .ssd import ssd_intra_chunk
 
 __all__ = ["ops", "ref", "flash_attention", "expert_matmul", "minplus_matmul",
-           "rglru_scan", "ssd_intra_chunk"]
+           "rglru_scan"]

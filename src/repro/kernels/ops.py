@@ -1,56 +1,48 @@
-"""jit'd public wrappers around the Pallas kernels (interpret=True on CPU, real
-Mosaic lowering on TPU), including the composed SSD forward that pairs the
-intra-chunk kernel with the jnp inter-chunk recurrence."""
+"""Public wrappers around the Pallas kernels: the composed SSD scan that
+takes the model layer's arguments, beside the kernels' own entry points.
+``interpret=True`` runs a kernel in the Pallas interpreter (the CPU tests);
+otherwise it lowers through Mosaic for the TPU."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from . import ssd
 from .flash_attention import flash_attention
 from .moe_gmm import expert_matmul
 from .rglru import rglru_scan
-from .ssd import ssd_intra_chunk
 
 
-def ssd_forward(xh, dtv, A, Bm, Cm, h0=None, chunk: int = 256,
-                interpret: bool | None = None):
-    """Full SSD layer forward via the Pallas intra-chunk kernel.
+def ssd_scan(x, dtv, A, Bm, Cm, D, h0=None, chunk: int = 256,
+             interpret: bool = False):
+    """The SSD layer's chunked scan and its skip, ``SSD(x) + D x``, through
+    the Pallas kernel pair.
 
-    xh: (B, S, H, P); dtv: (B, S, H) (softplus'd); A: (H,) positive rates;
-    Bm, Cm: (B, S, N).  Matches models.layers._ssd_chunked (the oracle).
-    Returns (y (B, S, H, P) fp32, h_last (B, H, P, N) fp32).
+    x: (B, S, H*P), read as bfloat16; dtv: (B, S, H) (softplus'd); A: (H,)
+    log-decay rates, negative, as ``models.layers._ssd_chunked`` (the
+    oracle) takes them; Bm, Cm: (B, S, N); D: (H,); h0: (B, H, P, N) or
+    None.  The shapes must satisfy ``ssd.ssd_tiles``.  Returns (y (B, S,
+    H*P) float32, h_last (B, H, P, N) float32), differentiable in every
+    array argument.  The within-chunk cumulative sum of ``dt * A`` is a
+    float32 product with a 0/1 triangle at ``Precision.HIGHEST``: exact
+    products, summed in float32, as the MXU does it faster than XLA's
+    windowed cumulative sum.
     """
-    Bsz, S, H, P = xh.shape
-    N = Bm.shape[-1]
+    Bsz, S, HP = x.shape
+    H, N = dtv.shape[-1], Bm.shape[-1]
     Q = min(chunk, S)
     nc = S // Q
-    assert nc * Q == S, "sequence must divide the chunk size"
-    xk = jnp.moveaxis(xh.reshape(Bsz, nc, Q, H, P), 3, 2)  # (B, nc, H, Q, P)
-    dtk = jnp.moveaxis(dtv.reshape(Bsz, nc, Q, H), 3, 2)  # (B, nc, H, Q)
-    Bc = Bm.reshape(Bsz, nc, Q, N)
-    Cc = Cm.reshape(Bsz, nc, Q, N)
-    y_intra, chunk_states, in_decay = ssd_intra_chunk(
-        xk, Bc, Cc, dtk, A, interpret=interpret)
-
-    # inter-chunk recurrence (tiny, sequential): h_{c} = decay_c * h_{c-1} + S_c
-    chunk_decay = in_decay[..., -1]  # (B, nc, H)
-    h_init = (h0.astype(jnp.float32) if h0 is not None
-              else jnp.zeros((Bsz, H, P, N), jnp.float32))
-
-    def step(h, inp):
-        cs, cd = inp  # (B,H,N,P), (B,H)
-        h_new = h * cd[:, :, None, None] + jnp.moveaxis(cs, 2, 3)
-        return h_new, h
-
-    h_last, h_prevs = jax.lax.scan(
-        step, h_init, (jnp.moveaxis(chunk_states, 1, 0),
-                       jnp.moveaxis(chunk_decay, 1, 0)))
-    h_prevs = jnp.moveaxis(h_prevs, 0, 1)  # (B, nc, H, P, N) state BEFORE chunk
-    y_inter = jnp.einsum("bcqn,bchq,bchpn->bchqp", Cc.astype(jnp.float32),
-                         in_decay, h_prevs)
-    y = jnp.moveaxis(y_intra + y_inter, 2, 3).reshape(Bsz, S, H, P)
-    return y, h_last
+    assert nc * Q == S, "sequence must be divisible by the chunk"
+    f32 = jnp.float32
+    dA = (dtv.astype(f32) * A).reshape(Bsz, nc, Q, H)
+    tri = jnp.tril(jnp.ones((Q, Q), f32))                   # k <= q
+    cum = jnp.einsum("qk,bckh->bcqh", tri, dA,
+                     precision=jax.lax.Precision.HIGHEST).reshape(Bsz, S, H)
+    if h0 is None:
+        h0 = jnp.zeros((Bsz, H, HP // H, N), f32)
+    return ssd.ssd_scan(x, dtv.astype(f32), cum, Bm.astype(f32),
+                        Cm.astype(f32), D.astype(f32), h0.astype(f32), Q,
+                        interpret)
 
 
-__all__ = ["flash_attention", "expert_matmul", "rglru_scan", "ssd_intra_chunk",
-           "ssd_forward"]
+__all__ = ["flash_attention", "expert_matmul", "rglru_scan", "ssd_scan"]

@@ -1,5 +1,6 @@
-"""Pure-jnp oracles for every Pallas kernel (the ground truth the shape/dtype
-sweeps in tests/test_kernels.py assert against)."""
+"""Pure-jnp oracles for the Pallas kernels (the ground truth the shape/dtype
+sweeps in tests/test_kernels.py assert against).  The SSD scan's oracle is
+the model's own path, ``models.layers._ssd_chunked``."""
 from __future__ import annotations
 
 import math
@@ -60,27 +61,3 @@ def reference_minplus(a, b):
     (int32; 0 for all-+inf columns, the jnp.argmin convention)."""
     cand = a[..., :, :, None] + b[..., None, :, :]  # (..., M, K, N)
     return cand.min(axis=-2), cand.argmin(axis=-2).astype(jnp.int32)
-
-
-def reference_ssd_intra_chunk(x, Bm, Cm, dt, A):
-    """Chunk-local SSD terms; mirrors models.layers._ssd_chunked's intra part.
-
-    x: (B, nc, H, Q, P); Bm/Cm: (B, nc, Q, N); dt: (B, nc, H, Q); A: (H,)>0.
-    """
-    x32 = x.astype(jnp.float32)
-    dt32 = dt.astype(jnp.float32)
-    dA = dt32 * (-A)[None, None, :, None]  # (B, nc, H, Q)
-    cum = jnp.cumsum(dA, axis=-1)
-    scores = jnp.einsum("bcqn,bckn->bcqk", Cm.astype(jnp.float32),
-                        Bm.astype(jnp.float32))
-    Q = x.shape[3]
-    causal = jnp.tril(jnp.ones((Q, Q), bool))
-    delta = cum[..., :, None] - cum[..., None, :]  # (B,nc,H,Q,K)
-    decay = jnp.exp(jnp.where(causal, delta, -jnp.inf))
-    w = scores[:, :, None] * decay
-    w = w * dt32[:, :, :, None, :]
-    y = jnp.einsum("bchqk,bchkp->bchqp", w, x32)
-    end_decay = jnp.exp(cum[..., -1:] - cum) * dt32  # (B, nc, H, Q)
-    hc = jnp.einsum("bchq,bcqn,bchqp->bchnp", end_decay,
-                    Bm.astype(jnp.float32), x32)
-    return y, hc, jnp.exp(cum)

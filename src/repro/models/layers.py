@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels import ops as ssd_ops
+from ..kernels.ssd import ssd_tiles
 from .sharding import active_rules, constrain, constrain_first
 
 Params = dict
@@ -550,8 +552,9 @@ def init_ssd(key, cfg: ModelConfig) -> Params:
 def _ssd_chunked(xh, dtv, A, Bm, Cm, h0=None, chunk=256):
     """Chunked SSD scan (Mamba-2 state-space duality, arXiv:2405.21060 Alg. 1).
 
-    xh: (B, S, H, P); dtv: (B, S, H) softplus'd; A: (H,) >0 decay rate;
-    Bm, Cm: (B, S, N).  Returns (y (B,S,H,P), h_last (B,H,P,N)).  fp32 math.
+    xh: (B, S, H, P); dtv: (B, S, H) softplus'd; A: (H,) negative log-decay
+    rates (-exp(A_log)); Bm, Cm: (B, S, N).  Returns (y (B,S,H,P), h_last
+    (B,H,P,N)).  fp32 math.
     """
     Bsz, S, H, P = xh.shape
     N = Bm.shape[-1]
@@ -602,6 +605,30 @@ def _ssd_chunked(xh, dtv, A, Bm, Cm, h0=None, chunk=256):
     return y, h_last
 
 
+def _ssd_scan(x, dtv, A, Bm, Cm, D, h0, chunk):
+    """The chunked SSD scan of ``x`` (B, S, H*P) and its skip: ``y = SSD(x)
+    + D x``, D: (H,); other arguments as :func:`_ssd_chunked` takes them.
+    Lowered for a TPU, where the shapes tile, it runs the Pallas kernel
+    pair (``kernels/ssd.py``); everywhere else it runs ``_ssd_chunked``, the
+    kernels' oracle.  Returns (y (B, S, H*P), h_last (B, H, P, N)),
+    float32."""
+    Bsz, S, HP = x.shape
+    H, N = dtv.shape[-1], Bm.shape[-1]
+    P = HP // H
+
+    def oracle(x, dtv, A, Bm, Cm, D, h0):
+        x = x.astype(jnp.float32)
+        y, h_last = _ssd_chunked(x.reshape(Bsz, S, H, P), dtv, A, Bm, Cm, h0,
+                                 chunk)
+        return y.reshape(Bsz, S, HP) + jnp.repeat(D, P) * x, h_last
+
+    if not ssd_tiles(min(chunk, S), H, P, N):
+        return oracle(x, dtv, A, Bm, Cm, D, h0)
+    return jax.lax.platform_dependent(
+        x, dtv, A, Bm, Cm, D, h0, tpu=partial(ssd_ops.ssd_scan, chunk=chunk),
+        default=oracle)
+
+
 def ssd_block(p, cfg: ModelConfig, x, ctx: Ctx, cache):
     dt_ = cdt(cfg)
     B, S, D = x.shape
@@ -621,31 +648,28 @@ def ssd_block(p, cfg: ModelConfig, x, ctx: Ctx, cache):
         conv_out = jax.nn.silu(conv_out)
         xs, Bm, Cm = jnp.split(conv_out, [Di, Di + N], axis=-1)
     with jax.named_scope("ssd_scan"):
-        xh = xs.reshape(B, S, H, P).astype(jnp.float32)
         dtv = jax.nn.softplus(dtv.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
         A = jnp.exp(p["A_log"])  # (H,) positive rates
         Bm32, Cm32 = Bm.astype(jnp.float32), Cm.astype(jnp.float32)
         if ctx.decoding and cache is not None:
+            xh = xs.reshape(B, S, H, P).astype(jnp.float32)
             h0 = cache["h"]  # (B, H, P, N)
             dA = jnp.exp(-dtv[:, 0] * A[None, :])  # (B, H)
             dBx = jnp.einsum("bh,bn,bhp->bhpn", dtv[:, 0], Bm32[:, 0],
                              xh[:, 0])
             h = h0 * dA[:, :, None, None] + dBx
             y = jnp.einsum("bn,bhpn->bhp", Cm32[:, 0], h)[:, None]
+            y = (y + p["D_skip"][None, None, :, None] * xh).reshape(B, S, Di)
             new_cache = {"h": h, "conv": new_conv}
         else:
             h0 = cache["h"] if (cache is not None and "h" in cache) else None
-            # NOTE: A enters negated inside `_ssd_chunked` via dA = dt*A with
-            # decay exp(-(cum_t - cum_s)); we pass positive rates and negate
-            # there.
-            y, h_last = _ssd_chunked(xh, dtv, -A, Bm32, Cm32, h0,
-                                     cfg.ssm_chunk)
+            # the scan takes the negative log-decay rates -A
+            y, h_last = _ssd_scan(xs, dtv, -A, Bm32, Cm32, p["D_skip"], h0,
+                                  cfg.ssm_chunk)
             new_cache = ({"h": h_last, "conv": new_conv} if cache is not None
                          else None)
     with jax.named_scope("ssd_gate_norm"):
-        y = y + p["D_skip"][None, None, :, None] * xh
-        y = y.reshape(B, S, Di).astype(dt_)
-        y = rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+        y = rmsnorm(y.astype(dt_) * jax.nn.silu(z), p["norm"], cfg.norm_eps)
     with jax.named_scope("ssd_out_proj"):
         return y @ p["w_out"].astype(dt_), new_cache
 

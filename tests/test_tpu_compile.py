@@ -82,9 +82,33 @@ def test_minplus_compiles_f32_through_mosaic(one_chip, a_shape, b_shape):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_ssd_scan_kernels_compile_at_cell_shapes(one_chip):
+    """The SSD scan's forward and backward kernels alone, at the shapes one
+    mamba2-370m layer gives them in the benchmark cell: microbatch 4, seq
+    2048, 32 heads of 64, state 128, chunk 256."""
+    from repro.kernels import ops
+
+    B, S, H, P, N = 4, 2048, 32, 64, 128
+
+    def loss(x, dt, A, Bm, Cm, D):
+        y, h = ops.ssd_scan(x, dt, A, Bm, Cm, D, None, chunk=256)
+        return jnp.sum(y) + jnp.sum(h)
+
+    f32 = jnp.float32
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        _spec((B, S, H * P), jnp.bfloat16, one_chip),
+        _spec((B, S, H), f32, one_chip), _spec((H,), f32, one_chip),
+        _spec((B, S, N), f32, one_chip), _spec((B, S, N), f32, one_chip),
+        _spec((H,), f32, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+
+
 def test_stage_apply_group_compiles_full_width(one_chip):
     """Forward and backward of one mamba2-370m group at published width, as
-    a pipeline stage runs it (microbatch 4, seq 2048)."""
+    a pipeline stage runs it (microbatch 4, seq 2048).  Lowered for the TPU,
+    the SSD scan runs as the Pallas kernel pair through Mosaic."""
     from repro.configs import get_config
     from repro.models import transformer as T
     from repro.models.layers import Ctx
@@ -109,3 +133,6 @@ def test_stage_apply_group_compiles_full_width(one_chip):
         group, x).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 1024**3
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
