@@ -8,8 +8,14 @@ A model is an embedding + a repeated *pattern* of block kinds + head.  Kinds:
   dec_block   decoder block: self-attn + cross-attn + MLP (enc-dec decoders)
   moe         mixture-of-experts FFN block (attention + MoE)
   moe_dense   MoE + parallel dense residual FFN (arctic)
+  attn_only   causal self-attention alone, no MLP (Nemotron-H's `*`)
+  moe_only    MoE FFN alone, no attention (Nemotron-H's `E`)
   rglru       RG-LRU recurrent block (Griffin/RecurrentGemma)
-  ssd         Mamba-2 state-space-duality block (attention-free)
+  ssd         Mamba-2 state-space-duality block (attention-free; Nemotron-H's
+              `M`)
+
+MoE blocks route over all `n_experts` and compute the experts this device
+holds (`moe_experts_held`, 0 = all), dropless (models/layers.moe_ffn).
 
 `n_layers` layers follow `pattern` cyclically; full pattern repetitions are
 executed under one `lax.scan` with stacked params, the remainder is unrolled.
@@ -39,19 +45,26 @@ class ModelConfig:
     final_softcap: float | None = None
     window: int | None = None  # local-attention window
     rope_theta: float = 10_000.0
-    mlp_variant: str = "swiglu"  # swiglu | geglu | gelu
+    use_rope: bool = True  # False: no position embedding at all (NoPE)
+    mlp_variant: str = "swiglu"  # swiglu | geglu | gelu | relu2
 
     # MoE
     n_experts: int = 0
     moe_top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    moe_router: str = "softmax"  # softmax | sigmoid (+ selection-only bias)
+    moe_routed_scale: float = 1.0  # times the normalised top-k weights
+    moe_shared_d_ff: int = 0  # one always-on shared expert of this width
+    moe_experts_held: int = 0  # experts on this device (0 = all)
 
     # SSM / recurrent
     ssm_state: int = 0
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     ssm_chunk: int = 256
+    ssm_heads: int = 0  # 0: ssm_expand * d_model // ssm_head_dim
+    ssm_groups: int = 1  # B/C groups; the gated norm is taken per group
+    conv_bias: bool = False  # the SSD block's depthwise conv has a bias
     rnn_width: int | None = None
     conv_width: int = 4
 
@@ -83,9 +96,22 @@ class ModelConfig:
     sharding_strategy: str = "fsdp"
 
     def __post_init__(self):
+        # a pattern read from JSON is a list; keep the config hashable
+        object.__setattr__(self, "pattern", tuple(self.pattern))
         assert self.n_layers >= 1 and self.d_model % 2 == 0
         if self.n_heads:
             assert self.n_heads % max(1, self.n_kv_heads) == 0
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of the SSD block's scan (heads x head_dim)."""
+        if self.ssm_heads:
+            return self.ssm_heads * self.ssm_head_dim
+        return self.ssm_expand * self.d_model
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.moe_experts_held or self.n_experts
 
     @property
     def resolved_head_dim(self) -> int:
@@ -107,8 +133,12 @@ class ModelConfig:
             n_experts=min(self.n_experts, 4),
             moe_d_ff=64 if self.n_experts else 0,
             moe_top_k=min(self.moe_top_k, 2),
+            moe_shared_d_ff=96 if self.moe_shared_d_ff else 0,
+            moe_experts_held=min(self.moe_experts_held, 4),
             ssm_state=min(self.ssm_state, 16),
             ssm_head_dim=16,
+            ssm_heads=min(self.ssm_heads, 8),
+            ssm_groups=min(self.ssm_groups, 2),
             ssm_chunk=16,
             rnn_width=64 if self.rnn_width else None,
             window=min(self.window, 32) if self.window else None,

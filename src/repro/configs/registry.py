@@ -1,5 +1,5 @@
-"""--arch registry: the 10 assigned architectures (+ the paper's own ResNet101
-profile for the planner examples)."""
+"""--arch registry: the 10 assigned architectures and Nemotron-3-Nano (+ the
+paper's own ResNet101 profile for the planner examples)."""
 from __future__ import annotations
 
 from . import (
@@ -7,6 +7,7 @@ from . import (
     gemma2_27b,
     llama_3_2_vision_90b,
     mamba2_370m,
+    nemotron3_nano_30b_a3b,
     qwen2_1_5b,
     qwen3_14b,
     qwen3_moe_30b_a3b,
@@ -29,7 +30,15 @@ ARCHS: dict[str, ModelConfig] = {
         recurrentgemma_9b,
         whisper_small,
         mamba2_370m,
+        nemotron3_nano_30b_a3b,
     )
+}
+
+
+# a whole period of the layer pattern, for archs whose published pattern is
+# one long non-repeating list: what a chain cut in depth repeats
+PERIODS: dict[str, tuple[str, ...]] = {
+    nemotron3_nano_30b_a3b.CONFIG.name: nemotron3_nano_30b_a3b.PERIOD,
 }
 
 

@@ -158,8 +158,8 @@ class ModelProfile:
                                      compare=False)
 
     def __post_init__(self) -> None:
-        if len(self.layers) < 2:
-            raise ValueError("a splittable model needs at least 2 layers")
+        if not self.layers:
+            raise ValueError("a model needs at least 1 layer")
 
     @property
     def L(self) -> int:

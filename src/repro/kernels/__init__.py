@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the substrate's compute hot spots (the paper itself
 has no kernel-level contribution — see DESIGN.md Sec. 2.3): flash attention,
-per-expert grouped matmul, RG-LRU recurrence, min-plus matmul, and the
-Mamba-2 SSD scan.
+RG-LRU recurrence, min-plus matmul, and the Mamba-2 SSD scan.  The MoE
+layer's grouped matmul is JAX's megablox kernel (``models.layers``).
 
 Each kernel: <name>.py (pl.pallas_call + explicit BlockSpec VMEM tiling),
 ops.py (wrappers), ref.py (pure-jnp oracles).  Validated in interpret mode on
@@ -13,8 +13,7 @@ oracle, and the path everywhere else, is ``models.layers._ssd_chunked``.
 from . import ops, ref
 from .flash_attention import flash_attention
 from .minplus import minplus_matmul
-from .moe_gmm import expert_matmul
 from .rglru import rglru_scan
 
-__all__ = ["ops", "ref", "flash_attention", "expert_matmul", "minplus_matmul",
+__all__ = ["ops", "ref", "flash_attention", "minplus_matmul",
            "rglru_scan"]

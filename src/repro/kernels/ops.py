@@ -9,7 +9,6 @@ import jax.numpy as jnp
 
 from . import ssd
 from .flash_attention import flash_attention
-from .moe_gmm import expert_matmul
 from .rglru import rglru_scan
 
 
@@ -20,7 +19,8 @@ def ssd_scan(x, dtv, A, Bm, Cm, D, h0=None, chunk: int = 256,
 
     x: (B, S, H*P), read as bfloat16; dtv: (B, S, H) (softplus'd); A: (H,)
     log-decay rates, negative, as ``models.layers._ssd_chunked`` (the
-    oracle) takes them; Bm, Cm: (B, S, N); D: (H,); h0: (B, H, P, N) or
+    oracle) takes them; Bm, Cm: (B, S, N), shared by the heads, or (B, S,
+    G, N), one for each run of H / G heads; D: (H,); h0: (B, H, P, N) or
     None.  The shapes must satisfy ``ssd.ssd_tiles``.  Returns (y (B, S,
     H*P) float32, h_last (B, H, P, N) float32), differentiable in every
     array argument.  The within-chunk cumulative sum of ``dt * A`` is a
@@ -30,6 +30,7 @@ def ssd_scan(x, dtv, A, Bm, Cm, D, h0=None, chunk: int = 256,
     """
     Bsz, S, HP = x.shape
     H, N = dtv.shape[-1], Bm.shape[-1]
+    Bm, Cm = Bm.reshape(Bsz, S, -1), Cm.reshape(Bsz, S, -1)  # group-major
     Q = min(chunk, S)
     nc = S // Q
     assert nc * Q == S, "sequence must be divisible by the chunk"
@@ -45,4 +46,4 @@ def ssd_scan(x, dtv, A, Bm, Cm, D, h0=None, chunk: int = 256,
                         interpret)
 
 
-__all__ = ["flash_attention", "expert_matmul", "rglru_scan", "ssd_scan"]
+__all__ = ["flash_attention", "rglru_scan", "ssd_scan"]

@@ -33,16 +33,6 @@ def reference_attention(q, k, v, *, causal=True, window=None, softcap=None,
     return o.reshape(B, Sq, Hq, hd).astype(q.dtype)
 
 
-def reference_expert_matmul(x, w, *, activation="none"):
-    out = jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32),
-                     w.astype(jnp.float32))
-    if activation == "silu":
-        out = out * jax.nn.sigmoid(out)
-    elif activation == "gelu":
-        out = jax.nn.gelu(out, approximate=True)
-    return out.astype(x.dtype)
-
-
 def reference_rglru_scan(a, b):
     """h_t = a_t h_{t-1} + b_t via associative scan (the model's own path)."""
 

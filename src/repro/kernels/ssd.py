@@ -4,11 +4,14 @@ The oracle is ``models.layers._ssd_chunked`` (arXiv:2405.21060, Alg. 1) and
 the skip ``D x``; this is the same algorithm with every O(Q^2) tensor kept
 in VMEM.  A head group is ``G`` heads whose ``G * P`` lanes make a whole
 number of 128-lane groups (two heads of 64 on mamba2-370m); one grid step is
-one (batch, chunk, run of head groups up to ``_STEP_LANES`` lanes).  Per
-head it computes the causal decay ``exp(where(q >= k, cum_q - cum_k,
--inf))``, the weights ``(C B^T) * decay * dt_k`` and, in the backward, their
-cotangents; none of them is written to HBM.  ``C B^T`` is shared by the heads
-and formed once per (batch, chunk), in VMEM.
+one (batch, chunk, run of head groups up to ``_STEP_LANES`` lanes, inside
+one SSM group).  Per head it computes the causal decay ``exp(where(q >= k,
+cum_q - cum_k, -inf))``, the weights ``(C B^T) * decay * dt_k`` and, in the
+backward, their cotangents; none of them is written to HBM.  ``B`` and ``C``
+belong to an SSM group, a run of ``H / n_groups`` heads (all heads on
+mamba2-370m, 8 heads of 64 on Nemotron-H, which is one 512-lane step):
+``C B^T`` is shared by the group's heads and formed once per (batch, chunk,
+SSM group), in VMEM, at the group's first grid step.
 
 The inter-chunk recurrence ``h_c = exp(cum_end) h_{c-1} + S_c`` runs inside
 the kernels: the chunk axis of the grid is sequential, and the state of
@@ -18,7 +21,8 @@ and no (Q, Q) tensor; the backward walks the chunks in reverse, carrying
 dL/dh in the ``dh0`` output block.
 
 Layouts: ``x``, ``y``, ``dx``: (B, S, H*P), the layout the block produces
-and consumes; ``B``, ``C``: (B, S, N); per-head vectors ``dt`` and ``cum``
+and consumes; ``B``, ``C``: (B, S, n_groups * N), group-major; per-head
+vectors ``dt`` and ``cum``
 (the within-chunk cumulative sum of ``dt * A``, computed in float32 by the
 caller): (B, S, H) for their columns and (B, H, S) for their rows.  A head's
 column or row is taken out of the block by a masked sum, exact, as Mosaic
@@ -51,10 +55,11 @@ def group_width(P: int) -> int:
     return math.lcm(P, 128)
 
 
-def ssd_tiles(Q: int, H: int, P: int, N: int) -> bool:
+def ssd_tiles(Q: int, H: int, P: int, N: int, n_groups: int = 1) -> bool:
     """Whether the kernels tile these shapes: chunk ``Q`` and state ``N`` in
-    whole 128-lane groups, heads in whole head groups."""
-    return Q % 128 == 0 and N % 128 == 0 and (H * P) % group_width(P) == 0
+    whole 128-lane groups, each SSM group's heads in whole head groups."""
+    return (Q % 128 == 0 and N % 128 == 0 and H % n_groups == 0
+            and (H // n_groups * P) % group_width(P) == 0)
 
 
 def _dot(a, b, contract):
@@ -115,13 +120,25 @@ def _decay(v, Q):
     return jnp.exp(jnp.where(causal, v["cum_c"] - v["cum_r"], -jnp.inf))
 
 
+def _first_of_group(g, spg):
+    """Whether grid step ``g`` is the first of its SSM group's ``spg`` steps
+    (``spg`` None: one SSM group over every step)."""
+    return g == 0 if spg is None else g % spg == 0
+
+
+def _last_of_group(g, spg):
+    if spg is None:
+        return g == pl.num_programs(2) - 1
+    return g % spg == spg - 1
+
+
 def _fwd_kernel(x_ref, dtc_ref, dtr_ref, cumc_ref, cumr_ref, b_ref, c_ref,
                 d_ref, h0_ref, y_ref, hprev_ref, h_ref, s_scr, *, G, P,
-                n_grp):
+                n_grp, spg):
     c, g = pl.program_id(1), pl.program_id(2)
     Q, gw = x_ref.shape[1], G * P
 
-    @pl.when(g == 0)
+    @pl.when(_first_of_group(g, spg))
     def _():
         s_scr[...] = _dot(c_ref[0], b_ref[0], _NT)                 # C B^T
 
@@ -152,11 +169,11 @@ def _fwd_kernel(x_ref, dtc_ref, dtr_ref, cumc_ref, cumr_ref, b_ref, c_ref,
 def _bwd_kernel(x_ref, dtc_ref, dtr_ref, cumc_ref, cumr_ref, b_ref, c_ref,
                 d_ref, hprev_ref, dy_ref, dhl_ref, dx_ref, ddtc_ref, ddtr_ref,
                 dcumc_ref, dcumr_ref, db_ref, dc_ref, dd_ref, dh_ref, s_scr,
-                ds_scr, *, G, P, n_grp):
+                ds_scr, *, G, P, n_grp, spg):
     c, g = pl.program_id(1), pl.program_id(2)
     Q, gw = x_ref.shape[1], G * P
 
-    @pl.when(g == 0)
+    @pl.when(_first_of_group(g, spg))
     def _():
         s_scr[...] = _dot(c_ref[0], b_ref[0], _NT)
         ds_scr[...] = jnp.zeros_like(ds_scr)
@@ -235,30 +252,34 @@ def _bwd_kernel(x_ref, dtc_ref, dtr_ref, cumc_ref, cumr_ref, b_ref, c_ref,
     ds_scr[...] = ds
     db_ref[0], dc_ref[0] = db, dc
 
-    @pl.when(g == pl.num_programs(2) - 1)    # scores' cotangent, all heads
+    @pl.when(_last_of_group(g, spg))   # scores' cotangent, the group's heads
     def _():
         dc_ref[0] += _dot(ds, Bm, _NN)
         db_ref[0] += _dot(ds, Cm, _TN)
 
 
-def _steps(H, P):
+def _steps(H, P, n_groups):
     """Head groups of one grid step (as many as fit ``_STEP_LANES`` lanes and
-    divide the groups), and the groups."""
+    divide an SSM group's head groups), the head groups, and the grid steps
+    of one SSM group (None where one SSM group spans every step)."""
     gw = group_width(P)
     n = H * P // gw
-    return max(k for k in range(1, max(1, _STEP_LANES // gw) + 1)
-               if n % k == 0), n
+    per_ssm = n // n_groups
+    n_grp = max(k for k in range(1, max(1, _STEP_LANES // gw) + 1)
+                if per_ssm % k == 0)
+    return n_grp, n, (per_ssm // n_grp if n_groups > 1 else None)
 
 
-def _specs(S, H, P, N, Q, reverse):
+def _specs(S, H, P, N, Q, n_groups, reverse):
     """BlockSpecs of the inputs both kernels share, and of the group state."""
     gw, nc = group_width(P), S // Q
-    n_grp, nG = _steps(H, P)
+    n_grp, nG, spg = _steps(H, P, n_groups)
     ch = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
     tile = pl.BlockSpec((1, Q, n_grp * gw), lambda b, c, g: (b, ch(c), g))
     cols = pl.BlockSpec((1, Q, H), lambda b, c, g: (b, ch(c), 0))
     rows = pl.BlockSpec((1, H, Q), lambda b, c, g: (b, 0, ch(c)))
-    bc = pl.BlockSpec((1, Q, N), lambda b, c, g: (b, ch(c), 0))
+    bc = pl.BlockSpec((1, Q, N), (lambda b, c, g: (b, ch(c), 0)) if spg is None
+                      else (lambda b, c, g: (b, ch(c), g // spg)))
     state = pl.BlockSpec((1, nG, gw, N), lambda b, c, g: (b, 0, 0, 0))
     hprev = pl.BlockSpec((1, 1, n_grp, gw, N),
                          lambda b, c, g: (b, ch(c), g, 0, 0))
@@ -285,14 +306,15 @@ def _rows(a):
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _forward(x, dt, cum, Bm, Cm, D, h0, chunk, interpret):
     Bsz, S, HP = x.shape
-    H, N = dt.shape[-1], Bm.shape[-1]
+    H, N = dt.shape[-1], h0.shape[-1]
     P, Q = HP // H, chunk
     W, nc = group_width(P), S // Q
-    n_grp, nG = _steps(H, P)
-    tile, cols, rows, bc, state, hprev = _specs(S, H, P, N, Q, False)
+    n_grp, nG, spg = _steps(H, P, Bm.shape[-1] // N)
+    tile, cols, rows, bc, state, hprev = _specs(S, H, P, N, Q,
+                                                Bm.shape[-1] // N, False)
     heads = pl.BlockSpec((1, H), lambda b, c, g: (0, 0))
     y, hprevs, h_last = _call(
-        functools.partial(_fwd_kernel, G=W // P, P=P, n_grp=n_grp),
+        functools.partial(_fwd_kernel, G=W // P, P=P, n_grp=n_grp, spg=spg),
         "ssd_scan_fwd", (Bsz, nc, nG // n_grp),
         [tile, cols, rows, cols, rows, bc, bc, heads, state],
         [tile, hprev, state],
@@ -309,22 +331,23 @@ def _forward(x, dt, cum, Bm, Cm, D, h0, chunk, interpret):
 def _backward(x, dt, cum, Bm, Cm, D, hprevs, dy, dh_last, dx_dtype, chunk,
               interpret):
     Bsz, S, HP = x.shape
-    H, N = dt.shape[-1], Bm.shape[-1]
+    H, N = dt.shape[-1], dh_last.shape[-1]
     P, Q = HP // H, chunk
     W, nc = group_width(P), S // Q
-    n_grp, nG = _steps(H, P)
-    tile, cols, rows, bc, state, hprev = _specs(S, H, P, N, Q, True)
+    n_grp, nG, spg = _steps(H, P, Bm.shape[-1] // N)
+    tile, cols, rows, bc, state, hprev = _specs(S, H, P, N, Q,
+                                                Bm.shape[-1] // N, True)
     heads = pl.BlockSpec((1, H), lambda b, c, g: (0, 0))
     per_row = pl.BlockSpec((1, 1, H), lambda b, c, g: (b, 0, 0))
     sds = jax.ShapeDtypeStruct
     dx, ddt_c, ddt_r, dcum_c, dcum_r, dB, dC, dD, dh0 = _call(
-        functools.partial(_bwd_kernel, G=W // P, P=P, n_grp=n_grp),
+        functools.partial(_bwd_kernel, G=W // P, P=P, n_grp=n_grp, spg=spg),
         "ssd_scan_bwd", (Bsz, nc, nG // n_grp),
         [tile, cols, rows, cols, rows, bc, bc, heads, hprev, tile, state],
         [tile, cols, rows, cols, rows, bc, bc, per_row, state],
         [sds((Bsz, S, HP), dx_dtype), sds((Bsz, S, H), f32),
          sds((Bsz, H, S), f32), sds((Bsz, S, H), f32), sds((Bsz, H, S), f32),
-         sds((Bsz, S, N), f32), sds((Bsz, S, N), f32), sds((Bsz, 1, H), f32),
+         sds(Bm.shape, f32), sds(Cm.shape, f32), sds((Bsz, 1, H), f32),
          sds((Bsz, nG, W, N), f32)],
         [pltpu.VMEM((Q, Q), f32), pltpu.VMEM((Q, Q), f32)], interpret,
     )(x, dt, _rows(dt), cum, _rows(cum), Bm, Cm, D[None], hprevs, dy,
@@ -340,8 +363,9 @@ def ssd_scan(x, dt, cum, Bm, Cm, D, h0, chunk, interpret=False):
     x: (B, S, H*P), any float dtype, read as bfloat16 (the precision every
     dot rounds it to; its cotangent comes back in its own dtype); dt, cum:
     (B, S, H) float32, ``cum`` the within-chunk cumulative sum of ``dt * A``
-    (A < 0); Bm, Cm: (B, S, N) float32, read as bfloat16; D: (H,) float32;
-    h0: (B, H, P, N) float32.  ``chunk`` divides S and the shapes satisfy
+    (A < 0); Bm, Cm: (B, S, n_groups * N) float32, group-major (heads
+    ``[i H / n_groups, (i + 1) H / n_groups)`` read group ``i``), read as
+    bfloat16; D: (H,) float32; h0: (B, H, P, N) float32.  ``chunk`` divides S and the shapes satisfy
     :func:`ssd_tiles`.  Returns (y (B, S, H*P) float32, h_last (B, H, P, N)
     float32)."""
     return _ssd_fwd(x, dt, cum, Bm, Cm, D, h0, chunk, interpret)[0]

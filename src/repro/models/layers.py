@@ -18,11 +18,12 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from ..configs.base import ModelConfig
 from ..kernels import ops as ssd_ops
 from ..kernels.ssd import ssd_tiles
-from .sharding import active_rules, constrain, constrain_first
+from .sharding import active_rules, constrain_first
 
 Params = dict
 Cache = Any
@@ -75,10 +76,14 @@ def _dense_init(key, shape, dtype, scale=None):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
 
-def rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps, groups: int = 1):
+    """RMS norm over the last axis, or over each of its ``groups`` equal
+    slices (Mamba-2's grouped gated norm), scaled by ``1 + scale``."""
     x32 = x.astype(jnp.float32)
+    if groups > 1:
+        x32 = x32.reshape(x.shape[:-1] + (groups, -1))
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    y = x32 * jax.lax.rsqrt(var + eps)
+    y = (x32 * jax.lax.rsqrt(var + eps)).reshape(x.shape)
     return (y * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
 
 
@@ -142,7 +147,7 @@ def _project_qkv(p, cfg: ModelConfig, xq, xkv, q_positions, kv_positions,
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    if apply_rope:
+    if apply_rope and cfg.use_rope:
         q = rope(q, q_positions, cfg.rope_theta)
         k = rope(k, kv_positions, cfg.rope_theta)
     return q, k, v
@@ -354,12 +359,19 @@ def init_mlp(key, cfg: ModelConfig, d_ff=None) -> Params:
             "w_up": _dense_init(ks[1], (D, F), dt),
             "w_down": _dense_init(ks[2], (F, D), dt),
         }
+    if cfg.mlp_variant == "relu2":  # Nemotron-H: no gate, no bias
+        return {"w_up": _dense_init(ks[0], (D, F), dt),
+                "w_down": _dense_init(ks[1], (F, D), dt)}
     return {  # plain gelu MLP (starcoder2 / whisper)
         "w_up": _dense_init(ks[0], (D, F), dt),
         "b_up": jnp.zeros((F,), dt),
         "w_down": _dense_init(ks[1], (F, D), dt),
         "b_down": jnp.zeros((D,), dt),
     }
+
+
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
 
 
 def mlp(p, cfg: ModelConfig, x):
@@ -369,6 +381,8 @@ def mlp(p, cfg: ModelConfig, x):
             jax.nn.gelu, approximate=True)
         h = act(x @ p["w_gate"].astype(dt)) * (x @ p["w_up"].astype(dt))
         return h @ p["w_down"].astype(dt)
+    if cfg.mlp_variant == "relu2":
+        return relu2(x @ p["w_up"].astype(dt)) @ p["w_down"].astype(dt)
     h = jax.nn.gelu(x @ p["w_up"].astype(dt) + p["b_up"].astype(dt),
                     approximate=True)
     return h @ p["w_down"].astype(dt) + p["b_down"].astype(dt)
@@ -376,76 +390,226 @@ def mlp(p, cfg: ModelConfig, x):
 
 # ====================================================================== MoE ====
 def init_moe(key, cfg: ModelConfig) -> Params:
-    D, F, E = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
+    """Router over all ``n_experts``; expert weights of the ``n_experts_held``
+    experts this device holds; the shared expert, where the config has one."""
+    D, F, E, Eh = cfg.d_model, cfg.moe_d_ff, cfg.n_experts, cfg.n_experts_held
     dt = pdt(cfg)
-    ks = jax.random.split(key, 4)
-    return {
-        "router": _dense_init(ks[0], (D, E), jnp.float32),  # router in fp32
-        "w_gate": _dense_init(ks[1], (E, D, F), dt),
-        "w_up": _dense_init(ks[2], (E, D, F), dt),
-        "w_down": _dense_init(ks[3], (E, F, D), dt),
-    }
+    ks = jax.random.split(key, 5)
+    p = {"router": _dense_init(ks[0], (D, E), jnp.float32)}  # router in fp32
+    if cfg.moe_router == "sigmoid":
+        p["router_bias"] = jnp.zeros((E,), jnp.float32)
+    if _gated(cfg):
+        p["w_gate"] = _dense_init(ks[1], (Eh, D, F), dt)
+    p["w_up"] = _dense_init(ks[2], (Eh, D, F), dt)
+    p["w_down"] = _dense_init(ks[3], (Eh, F, D), dt)
+    if cfg.moe_shared_d_ff:
+        p["shared"] = init_mlp(ks[4], cfg, cfg.moe_shared_d_ff)
+    return p
 
 
-MOE_GROUP = 1024  # tokens per dispatch group (bounds the one-hot dispatch tensor)
+def _gated(cfg: ModelConfig) -> bool:
+    return cfg.mlp_variant != "relu2"
 
 
-def moe_ffn(p, cfg: ModelConfig, x):
-    """GShard-style capacity-based top-k dispatch (EP-shardable einsums).
+def route(p, cfg: ModelConfig, x):
+    """The router on tokens ``x`` (T, D): top-k expert ids (T, k) and their
+    weights (T, k), float32.  ``softmax``: the top-k of the softmax,
+    renormalised.  ``sigmoid`` (DeepSeek-V3 / Nemotron-H): sigmoid scores;
+    the top-k of the scores plus the selection-only ``router_bias``; the
+    chosen scores renormalised.  Both are scaled by ``moe_routed_scale``.
+    Logits and selection run in float32 at full matmul precision."""
+    logits = jnp.dot(x.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    if cfg.moe_router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + jax.lax.stop_gradient(p["router_bias"])
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        choice = scores
+    _, idx = jax.lax.top_k(choice, cfg.moe_top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx, w * cfg.moe_routed_scale, scores
 
-    Tokens are processed in groups of MOE_GROUP so the (T, E, C) dispatch
-    one-hot stays O(T^2 k / E) *per group* instead of per batch.  Load-balance
-    auxiliary loss is returned via `moe_ffn.aux` on the fly (summed by caller).
-    """
-    B, S, D = x.shape
-    E, k = cfg.n_experts, cfg.moe_top_k
-    T = B * S
-    g = min(MOE_GROUP, T)
-    n_groups = max(1, T // g)
-    toks = x.reshape(n_groups, g, D)
-    C = max(1, int(g * k / E * cfg.capacity_factor))
 
-    logits = jnp.einsum("gtd,de->gte", toks.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, idx = jax.lax.top_k(probs, k)  # (G, g, k)
-    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
+# grouped-matmul tiles (rows, contraction, columns) on the TPU: 256 rows keep
+# an expert's partial tiles few at a few hundred rows an expert, and 1024-wide
+# blocks keep the grid a few hundred steps (~7 MB of VMEM with buffering)
+GMM_TILING = (256, 1024, 1024)
 
-    # position-in-expert via cumulative counts across the k slots
-    mask = jax.nn.one_hot(idx, E, dtype=jnp.int32)  # (G, g, k, E)
-    pos_in_slot = jnp.cumsum(mask, axis=1) - mask  # tokens before me, same slot
-    offset = jnp.cumsum(mask.sum(axis=1, keepdims=True), axis=2) - mask.sum(
-        axis=1, keepdims=True)  # earlier slots' totals
-    pos = pos_in_slot + offset  # (G, g, k, E)
-    keep = (pos < C) & (mask > 0)
-    # dispatch/combine tensors (G, g, E, C); accumulate per slot so the
-    # (g, k, E, C) intermediate is never materialized
-    disp = jnp.zeros((n_groups, g, E, C), cdt(cfg))
-    comb = jnp.zeros((n_groups, g, E, C), jnp.float32)
-    for j in range(k):
-        oh = jax.nn.one_hot(pos[:, :, j], C, dtype=jnp.float32)  # (G, g, E, C)
-        oh = oh * keep[:, :, j, :, None]
-        disp = disp + oh.astype(cdt(cfg))
-        comb = comb + oh * gate_vals[:, :, j][:, :, None, None]
-    # EP layout: token groups on the DP axes, experts on 'model'; the
-    # dispatch/combine einsums become the all-to-alls of expert parallelism
-    disp = constrain(disp, ("batch", None, "expert", None))
-    comb = constrain(comb, ("batch", None, "expert", None))
-    expert_in = constrain(jnp.einsum("gtec,gtd->gecd", disp, toks),
-                          ("batch", "expert", None, None))
-    act = jax.nn.silu if cfg.mlp_variant != "gelu" else jax.nn.gelu
+
+def _grouped_matmul(lhs, rhs, sizes):
+    """``lhs`` (m, K) rows sorted by group against ``rhs`` (g, K, N): rows of
+    group i times ``rhs[i]``, for the first g of the ``sizes`` (g + 1,)
+    groups; the last group's rows (and any past the sizes) give zeros and
+    cost no matmul.  On a TPU the megablox grouped-matmul kernel, which
+    visits only the tiles of the first g groups; elsewhere ``ragged_dot``."""
+    def tpu(lhs, rhs, sizes):
+        from jax.experimental.pallas.ops.tpu.megablox import ops as mblx
+
+        return mblx.gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
+                        tiling=GMM_TILING, group_offset=jnp.zeros((),
+                                                                 jnp.int32))
+
+    def default(lhs, rhs, sizes):
+        return jax.lax.ragged_dot(lhs, rhs, sizes[:-1])
+
+    return jax.lax.platform_dependent(lhs, rhs, sizes, tpu=tpu,
+                                      default=default)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(x, order, inv, k):
+    """Rows ``x[order // k]`` (T*k, D) of tokens ``x`` (T, D), one for each
+    (token, slot) pair in sorted order, where ``order`` is a permutation of
+    the pairs and ``inv`` its inverse; the transpose gathers each pair's
+    cotangent back (``inv``, not a scatter) and sums a token's ``k`` slots.
+    With ``k`` 1 it permutes rows: ``x[order]``."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inv, k):
+    return x[order // k], inv
+
+
+def _dispatch_bwd(k, inv, g):
+    T = inv.shape[0] // k
+    return g[inv].reshape(T, k, -1).sum(axis=1), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def _routed(p, cfg: ModelConfig, toks, idx, w, first, Eh: int):
+    """The part of tokens ``toks`` (T, D), routed to ``idx`` with weights
+    ``w`` (T, k), that experts ``[first, first + Eh)`` give, their weights
+    ``p`` holding those ``Eh``: (y (T, D), rows of each held expert (Eh,)).
+
+    Every (token, slot) pair is sorted by expert, held experts first; the
+    held experts' rows go through grouped matmuls (``_grouped_matmul``) and
+    every one of them is computed, none dropped; the other pairs' rows cost
+    no expert matmul and add nothing here."""
+    T, D = toks.shape
+    k = cfg.moe_top_k
     dt = cdt(cfg)
-    h = act(jnp.einsum("gecd,edf->gecf", expert_in, p["w_gate"].astype(dt)))
-    h = h * jnp.einsum("gecd,edf->gecf", expert_in, p["w_up"].astype(dt))
-    h = constrain(h, ("batch", "expert", None, None))
-    expert_out = constrain(jnp.einsum("gecf,efd->gecd", h, p["w_down"].astype(dt)),
-                           ("batch", "expert", None, None))
-    out = jnp.einsum("gtec,gecd->gtd", comb.astype(dt), expert_out)
+    with jax.named_scope("moe_dispatch"):
+        local = idx.reshape(-1) - first
+        held = (local >= 0) & (local < Eh)
+        key = jnp.where(held, local, Eh).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        sizes = jnp.bincount(key, length=Eh + 1).astype(jnp.int32)
+        rows = _dispatch_rows(toks.astype(dt), order, inv, k)
+        pad = -(T * k) % GMM_TILING[0]   # the kernel tiles whole row blocks
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        sizes = sizes.at[Eh].add(pad)
+    with jax.named_scope("moe_experts"):
+        up = _grouped_matmul(rows, p["w_up"].astype(dt), sizes)
+        if _gated(cfg):
+            gate = _grouped_matmul(rows, p["w_gate"].astype(dt), sizes)
+            act = (jax.nn.silu if cfg.mlp_variant == "swiglu"
+                   else partial(jax.nn.gelu, approximate=True))
+            h = act(gate) * up
+        else:
+            h = relu2(up)
+        out = _grouped_matmul(h, p["w_down"].astype(dt), sizes)[:T * k]
+    with jax.named_scope("moe_combine"):
+        pairs = _dispatch_rows(out, inv, order, 1).reshape(T, k, D)
+        wk = jnp.where(held.reshape(T, k), w, 0.0).astype(dt)
+        y = jnp.einsum("tk,tkd->td", wk, pairs)
+    return y, sizes[:Eh]
 
-    # load-balance aux loss (Switch): E * sum_e f_e * P_e
-    f = mask[:, :, 0, :].astype(jnp.float32).mean(axis=1)  # top-1 routing frac
-    P = probs.mean(axis=1)
-    aux = E * jnp.mean(jnp.sum(f * P, axis=-1))
-    return out.reshape(B, S, D), aux
+
+def _expert_axes(cfg: ModelConfig):
+    """The active mesh rules where they shard this layer's experts: every
+    expert held here (``moe_experts_held`` 0) and the ``expert`` axes, of
+    more than one device, dividing ``n_experts``; else None."""
+    rules = active_rules()
+    if rules is None or cfg.moe_experts_held:
+        return None
+    n = rules.axes_size(rules.expert)
+    return rules if n > 1 and cfg.n_experts % n == 0 else None
+
+
+def _routed_expert_parallel(p, cfg: ModelConfig, toks, idx, w, rules):
+    """``_routed`` over all experts, as expert parallelism: each device of
+    the ``expert`` axes holds ``n_experts / n`` of them (the weights stay
+    sharded as the parameter rules lay them out) and computes their share
+    of its tokens, ``first`` = its index on those axes times its experts;
+    the shares are summed over the axes.  Tokens keep their batch axes
+    (those the expert axes do not take)."""
+    axes = rules.expert
+    Eh = cfg.n_experts // rules.axes_size(axes)
+    batch = tuple(a for a in rules.batch if a not in axes)
+    while batch and toks.shape[0] % rules.axes_size(batch):
+        batch = batch[:-1]
+    rows = PartitionSpec(batch or None)
+    experts = {n: p[n] for n in ("w_gate", "w_up", "w_down") if n in p}
+
+    def share(experts, toks, idx, w):
+        first = jax.lax.axis_index(axes) * Eh
+        y, sizes = _routed(experts, cfg, toks, idx, w, first, Eh)
+        y = jax.lax.psum(y.astype(jnp.float32), axes)
+        return y, (jax.lax.psum(sizes, batch) if batch else sizes)
+
+    return jax.shard_map(
+        share, mesh=rules.mesh,
+        in_specs=(jax.tree.map(lambda _: PartitionSpec(axes), experts),
+                  rows, rows, rows),
+        out_specs=(rows, PartitionSpec(axes)), check_vma=False)(experts, toks, idx, w)
+
+
+def moe_ffn(p, cfg: ModelConfig, x, first: int = 0):
+    """Dropless expert-share MoE: routes every token over all ``n_experts``
+    and computes the part of the output that the experts this device holds,
+    ``[first, first + n_experts_held)``, give (``_routed``), plus the shared
+    expert.  Under mesh rules whose ``expert`` axes shard a layer that holds
+    every expert, the experts run expert-parallel over those axes
+    (``_routed_expert_parallel``).
+
+    Returns (y (B, S, D), stats): the Switch load-balance loss under
+    ``aux`` (softmax router; the sigmoid router balances by its bias and
+    reads 0), the rows the held experts computed (``moe_rows``) and the
+    largest held expert's rows (``moe_max_rows``)."""
+    B, S, D = x.shape
+    T = B * S
+    dt = cdt(cfg)
+    toks = x.reshape(T, D)
+    with jax.named_scope("moe_router"):
+        idx, w, scores = route(p, cfg, toks)
+    rules = _expert_axes(cfg)
+    if rules is not None:
+        y, sizes = _routed_expert_parallel(p, cfg, toks, idx, w, rules)
+    else:
+        y, sizes = _routed(p, cfg, toks, idx, w, first, cfg.n_experts_held)
+    y = y.astype(dt)
+    if "shared" in p:
+        with jax.named_scope("moe_shared"):
+            y = y + mlp(p["shared"], cfg, toks.astype(dt))
+    stats = {"aux": jnp.zeros((), jnp.float32),
+             "moe_rows": jnp.sum(sizes),
+             "moe_max_rows": jnp.max(sizes)}
+    if cfg.moe_router == "softmax":
+        # load-balance aux loss (Switch): E * sum_e f_e * P_e
+        f = jnp.mean(jax.nn.one_hot(idx[:, 0], cfg.n_experts,
+                                    dtype=jnp.float32), axis=0)
+        stats["aux"] = cfg.n_experts * jnp.sum(f * jnp.mean(scores, axis=0))
+    return y.reshape(B, S, D).astype(x.dtype), stats
+
+
+def no_stats() -> dict:
+    """A block's statistics where it has none: the auxiliary loss and the
+    MoE counters of ``moe_ffn``."""
+    return {"aux": jnp.zeros((), jnp.float32),
+            "moe_rows": jnp.zeros((), jnp.int32),
+            "moe_max_rows": jnp.zeros((), jnp.int32)}
+
+
+def add_stats(a: dict, b: dict) -> dict:
+    """Two blocks' statistics together: sums, and maxima of ``*max*``."""
+    return {k: (jnp.maximum(a[k], b[k]) if "max" in k else a[k] + b[k])
+            for k in a}
 
 
 # =================================================================== RG-LRU ====
@@ -529,17 +693,21 @@ def init_rglru_cache(cfg: ModelConfig, batch: int):
 
 
 # ================================================================ Mamba-2 SSD ==
+def _ssd_dims(cfg: ModelConfig) -> tuple[int, int, int, int, int]:
+    """(Di, H, P, N, G): scan channels, heads, head width, state, groups."""
+    Di, P = cfg.ssm_inner, cfg.ssm_head_dim
+    return Di, Di // P, P, cfg.ssm_state, cfg.ssm_groups
+
+
 def init_ssd(key, cfg: ModelConfig) -> Params:
     D = cfg.d_model
-    Di = cfg.ssm_expand * D
-    H = Di // cfg.ssm_head_dim
-    N = cfg.ssm_state
+    Di, H, _, N, G = _ssd_dims(cfg)
     dt = pdt(cfg)
     ks = jax.random.split(key, 4)
-    conv_dim = Di + 2 * N
-    return {
-        # projects to [z (Di), x (Di), B (N), C (N), dt (H)]
-        "w_in": _dense_init(ks[0], (D, 2 * Di + 2 * N + H), dt),
+    conv_dim = Di + 2 * G * N
+    p = {
+        # projects to [z (Di), x (Di), B (G*N), C (G*N), dt (H)]
+        "w_in": _dense_init(ks[0], (D, 2 * Di + 2 * G * N + H), dt),
         "conv_w": _dense_init(ks[1], (cfg.conv_width, conv_dim), dt, scale=0.3),
         "A_log": jnp.log(jnp.linspace(1.0, 16.0, H)).astype(jnp.float32),
         "D_skip": jnp.ones((H,), jnp.float32),
@@ -547,24 +715,31 @@ def init_ssd(key, cfg: ModelConfig) -> Params:
         "norm": jnp.zeros((Di,), dt),
         "w_out": _dense_init(ks[2], (Di, D), dt),
     }
+    if cfg.conv_bias:
+        p["conv_b"] = jnp.zeros((conv_dim,), dt)
+    return p
 
 
 def _ssd_chunked(xh, dtv, A, Bm, Cm, h0=None, chunk=256):
     """Chunked SSD scan (Mamba-2 state-space duality, arXiv:2405.21060 Alg. 1).
 
     xh: (B, S, H, P); dtv: (B, S, H) softplus'd; A: (H,) negative log-decay
-    rates (-exp(A_log)); Bm, Cm: (B, S, N).  Returns (y (B,S,H,P), h_last
-    (B,H,P,N)).  fp32 math.
+    rates (-exp(A_log)); Bm, Cm: (B, S, N), shared by the heads, or (B, S,
+    G, N), group g read by heads [g H/G, (g+1) H/G).  Returns (y (B,S,H,P),
+    h_last (B,H,P,N)).  fp32 math.
     """
     Bsz, S, H, P = xh.shape
-    N = Bm.shape[-1]
+    if Bm.ndim == 3:
+        Bm, Cm = Bm[:, :, None], Cm[:, :, None]
+    G, N = Bm.shape[2], Bm.shape[3]
+    J = H // G                      # heads of a group
     Q = min(chunk, S)
     nc = S // Q
     assert nc * Q == S, "sequence must be divisible by ssm_chunk"
-    xc = xh.reshape(Bsz, nc, Q, H, P)
+    xc = xh.reshape(Bsz, nc, Q, G, J, P)
     dtc = dtv.reshape(Bsz, nc, Q, H)
-    Bc = Bm.reshape(Bsz, nc, Q, N)
-    Cc = Cm.reshape(Bsz, nc, Q, N)
+    Bc = Bm.reshape(Bsz, nc, Q, G, N)
+    Cc = Cm.reshape(Bsz, nc, Q, G, N)
 
     dA = dtc * A[None, None, None, :]  # (B, nc, Q, H): -log decay per step
     cum = jnp.cumsum(dA, axis=2)  # within-chunk cumulative
@@ -572,18 +747,19 @@ def _ssd_chunked(xh, dtv, A, Bm, Cm, h0=None, chunk=256):
     # Mask the EXPONENT, not the exp: non-causal entries have positive
     # cum_q - cum_k that overflows exp in fp32, and 0 * d(inf) = NaN in the
     # backward pass (exposed by pipeline bubble ticks).
-    scores = jnp.einsum("bcqn,bckn->bcqk", Cc, Bc)  # shared across heads
+    scores = jnp.einsum("bcqgn,bckgn->bcqkg", Cc, Bc)  # shared by a group
     causal = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None]
     delta = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,K,H)
     decay = jnp.exp(jnp.where(causal, delta, -jnp.inf))
-    w = scores[..., None] * decay
+    w = jnp.repeat(scores, J, axis=-1) * decay
     w = w * dtc[:, :, None, :, :]  # dt_k factor (B,nc,Q,K,H)
-    y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", w, xc)
+    y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", w, xc.reshape(Bsz, nc, Q, H, P))
 
     # chunk states: h_c = sum_k exp(cum_end - cum_k) dt_k B_k x_k
     end_decay = jnp.exp(cum[:, :, -1:, :] - cum)  # (B, nc, Q, H)
-    state_w = end_decay * dtc  # (B, nc, Q, H)
-    chunk_states = jnp.einsum("bcqh,bcqn,bcqhp->bchpn", state_w, Bc, xc)
+    state_w = (end_decay * dtc).reshape(Bsz, nc, Q, G, J)
+    chunk_states = jnp.einsum("bcqgj,bcqgn,bcqgjp->bcgjpn", state_w, Bc,
+                              xc).reshape(Bsz, nc, H, P, N)
 
     # inter-chunk scan over nc
     chunk_decay = jnp.exp(cum[:, :, -1, :])  # (B, nc, H) total decay of chunk
@@ -599,9 +775,10 @@ def _ssd_chunked(xh, dtv, A, Bm, Cm, h0=None, chunk=256):
         step, h_init, (jnp.moveaxis(chunk_states, 1, 0),
                        jnp.moveaxis(chunk_decay, 1, 0)))
     h_prevs = jnp.moveaxis(h_prevs, 0, 1)  # (B, nc, H, P, N): state BEFORE chunk
-    in_decay = jnp.exp(cum)  # decay from chunk start to position
-    y_inter = jnp.einsum("bcqn,bcqh,bchpn->bcqhp", Cc, in_decay, h_prevs)
-    y = (y_intra + y_inter).reshape(Bsz, S, H, P)
+    in_decay = jnp.exp(cum).reshape(Bsz, nc, Q, G, J)  # chunk start -> position
+    y_inter = jnp.einsum("bcqgn,bcqgj,bcgjpn->bcqgjp", Cc, in_decay,
+                         h_prevs.reshape(Bsz, nc, G, J, P, N))
+    y = (y_intra + y_inter.reshape(Bsz, nc, Q, H, P)).reshape(Bsz, S, H, P)
     return y, h_last
 
 
@@ -614,6 +791,7 @@ def _ssd_scan(x, dtv, A, Bm, Cm, D, h0, chunk):
     float32."""
     Bsz, S, HP = x.shape
     H, N = dtv.shape[-1], Bm.shape[-1]
+    G = Bm.shape[2] if Bm.ndim == 4 else 1
     P = HP // H
 
     def oracle(x, dtv, A, Bm, Cm, D, h0):
@@ -622,7 +800,7 @@ def _ssd_scan(x, dtv, A, Bm, Cm, D, h0, chunk):
                                  chunk)
         return y.reshape(Bsz, S, HP) + jnp.repeat(D, P) * x, h_last
 
-    if not ssd_tiles(min(chunk, S), H, P, N):
+    if not ssd_tiles(min(chunk, S), H, P, N, G):
         return oracle(x, dtv, A, Bm, Cm, D, h0)
     return jax.lax.platform_dependent(
         x, dtv, A, Bm, Cm, D, h0, tpu=partial(ssd_ops.ssd_scan, chunk=chunk),
@@ -632,34 +810,39 @@ def _ssd_scan(x, dtv, A, Bm, Cm, D, h0, chunk):
 def ssd_block(p, cfg: ModelConfig, x, ctx: Ctx, cache):
     dt_ = cdt(cfg)
     B, S, D = x.shape
-    Di = cfg.ssm_expand * D
-    H = Di // cfg.ssm_head_dim
-    P = cfg.ssm_head_dim
-    N = cfg.ssm_state
+    Di, H, P, N, G = _ssd_dims(cfg)
     with jax.named_scope("ssd_in_proj"):
         zxbcdt = x @ p["w_in"].astype(dt_)
         z, xs, Bm, Cm, dtv = jnp.split(
-            zxbcdt, [Di, 2 * Di, 2 * Di + N, 2 * Di + 2 * N], axis=-1)
+            zxbcdt, [Di, 2 * Di, 2 * Di + G * N, 2 * Di + 2 * G * N], axis=-1)
     with jax.named_scope("ssd_conv"):
         conv_in = jnp.concatenate([xs, Bm, Cm], axis=-1)
         conv_state = cache.get("conv") if cache else None
         conv_out, new_conv = _causal_depthwise_conv(
             conv_in, p["conv_w"].astype(dt_), conv_state)
+        if "conv_b" in p:
+            conv_out = conv_out + p["conv_b"].astype(dt_)
         conv_out = jax.nn.silu(conv_out)
-        xs, Bm, Cm = jnp.split(conv_out, [Di, Di + N], axis=-1)
+        xs, Bm, Cm = jnp.split(conv_out, [Di, Di + G * N], axis=-1)
     with jax.named_scope("ssd_scan"):
         dtv = jax.nn.softplus(dtv.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
         A = jnp.exp(p["A_log"])  # (H,) positive rates
         Bm32, Cm32 = Bm.astype(jnp.float32), Cm.astype(jnp.float32)
+        if G > 1:
+            Bm32, Cm32 = (a.reshape(B, S, G, N) for a in (Bm32, Cm32))
         if ctx.decoding and cache is not None:
-            xh = xs.reshape(B, S, H, P).astype(jnp.float32)
+            J = H // G
+            xh = xs.reshape(B, G, J, P).astype(jnp.float32)  # S == 1
+            Bg, Cg = Bm32.reshape(B, G, N), Cm32.reshape(B, G, N)
             h0 = cache["h"]  # (B, H, P, N)
             dA = jnp.exp(-dtv[:, 0] * A[None, :])  # (B, H)
-            dBx = jnp.einsum("bh,bn,bhp->bhpn", dtv[:, 0], Bm32[:, 0],
-                             xh[:, 0])
+            dBx = jnp.einsum("bgj,bgn,bgjp->bgjpn", dtv[:, 0].reshape(B, G, J),
+                             Bg, xh).reshape(B, H, P, N)
             h = h0 * dA[:, :, None, None] + dBx
-            y = jnp.einsum("bn,bhpn->bhp", Cm32[:, 0], h)[:, None]
-            y = (y + p["D_skip"][None, None, :, None] * xh).reshape(B, S, Di)
+            y = jnp.einsum("bgn,bgjpn->bgjp", Cg,
+                           h.reshape(B, G, J, P, N)).reshape(B, 1, H, P)
+            y = (y + p["D_skip"][None, None, :, None]
+                 * xh.reshape(B, 1, H, P)).reshape(B, S, Di)
             new_cache = {"h": h, "conv": new_conv}
         else:
             h0 = cache["h"] if (cache is not None and "h" in cache) else None
@@ -669,16 +852,16 @@ def ssd_block(p, cfg: ModelConfig, x, ctx: Ctx, cache):
             new_cache = ({"h": h_last, "conv": new_conv} if cache is not None
                          else None)
     with jax.named_scope("ssd_gate_norm"):
-        y = rmsnorm(y.astype(dt_) * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+        # norm_before_gate=False, per group of Di / G channels (Nemotron-H)
+        y = rmsnorm(y.astype(dt_) * jax.nn.silu(z), p["norm"], cfg.norm_eps,
+                    groups=G)
     with jax.named_scope("ssd_out_proj"):
         return y @ p["w_out"].astype(dt_), new_cache
 
 
 def init_ssd_cache(cfg: ModelConfig, batch: int):
-    Di = cfg.ssm_expand * cfg.d_model
-    H = Di // cfg.ssm_head_dim
-    conv_dim = Di + 2 * cfg.ssm_state
+    Di, H, P, N, G = _ssd_dims(cfg)
     return {
-        "h": jnp.zeros((batch, H, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32),
-        "conv": jnp.zeros((batch, cfg.conv_width - 1, conv_dim), cdt(cfg)),
+        "h": jnp.zeros((batch, H, P, N), jnp.float32),
+        "conv": jnp.zeros((batch, cfg.conv_width - 1, Di + 2 * G * N), cdt(cfg)),
     }

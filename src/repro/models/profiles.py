@@ -43,10 +43,13 @@ def _attn_flops(cfg: ModelConfig, S: int, S_kv: int | None = None,
     return proj + attn
 
 
+def _n_mats(cfg: ModelConfig) -> int:
+    return 3 if cfg.mlp_variant in ("swiglu", "geglu") else 2
+
+
 def _mlp_flops(cfg: ModelConfig, S: int, d_ff: int | None = None) -> float:
     F = d_ff if d_ff is not None else cfg.d_ff
-    n_mats = 3 if cfg.mlp_variant in ("swiglu", "geglu") else 2
-    return 2 * n_mats * S * cfg.d_model * F
+    return 2 * _n_mats(cfg) * S * cfg.d_model * F
 
 
 def _attn_params(cfg: ModelConfig) -> int:
@@ -57,8 +60,23 @@ def _attn_params(cfg: ModelConfig) -> int:
 
 def _mlp_params(cfg: ModelConfig, d_ff: int | None = None) -> int:
     F = d_ff if d_ff is not None else cfg.d_ff
-    n_mats = 3 if cfg.mlp_variant in ("swiglu", "geglu") else 2
-    return n_mats * cfg.d_model * F
+    return _n_mats(cfg) * cfg.d_model * F
+
+
+def _moe_cost(cfg: ModelConfig, S: int) -> tuple[float, int]:
+    """(fw flops, params) of an MoE FFN on this device: the router over all
+    experts, the held experts at their expected load (top_k * held / E rows
+    a token), the shared expert."""
+    D, E, Eh = cfg.d_model, cfg.n_experts, cfg.n_experts_held
+    fl = 2 * S * D * E
+    fl += cfg.moe_top_k * Eh / E * _mlp_flops(cfg, S, cfg.moe_d_ff)
+    pr = D * E + Eh * _mlp_params(cfg, cfg.moe_d_ff)
+    if cfg.moe_router == "sigmoid":
+        pr += E                                  # the correction bias
+    if cfg.moe_shared_d_ff:
+        fl += _mlp_flops(cfg, S, cfg.moe_shared_d_ff)
+        pr += _mlp_params(cfg, cfg.moe_shared_d_ff)
+    return fl, pr
 
 
 def _block_cost(cfg: ModelConfig, kind: str, S: int, mode: str,
@@ -72,15 +90,19 @@ def _block_cost(cfg: ModelConfig, kind: str, S: int, mode: str,
         fl += _attn_flops(cfg, S, S_kv, True, window)
         pr += _attn_params(cfg)
         if kind in ("moe", "moe_dense"):
-            fl += 2 * S * D * cfg.n_experts  # router
-            fl += cfg.moe_top_k * _mlp_flops(cfg, S, cfg.moe_d_ff)
-            pr += D * cfg.n_experts + cfg.n_experts * 3 * D * cfg.moe_d_ff
+            f, p = _moe_cost(cfg, S)
+            fl, pr = fl + f, pr + p
             if kind == "moe_dense":
                 fl += _mlp_flops(cfg, S)
                 pr += _mlp_params(cfg)
         else:
             fl += _mlp_flops(cfg, S)
             pr += _mlp_params(cfg)
+    elif kind == "attn_only":
+        fl += _attn_flops(cfg, S, S_kv, True)
+        pr += _attn_params(cfg)
+    elif kind == "moe_only":
+        fl, pr = _moe_cost(cfg, S)
     elif kind == "xattn":
         M = cfg.memory_len
         fl += 2 * S * D * cfg.n_heads * cfg.resolved_head_dim * 2  # q, o proj
@@ -104,16 +126,18 @@ def _block_cost(cfg: ModelConfig, kind: str, S: int, mode: str,
         fl += _mlp_flops(cfg, S)
         pr += 2 * D * W + 2 * W * W + cfg.conv_width * W + W * D + _mlp_params(cfg)
     elif kind == "ssd":
-        Di = cfg.ssm_expand * D
-        N = cfg.ssm_state
+        Di = cfg.ssm_inner
+        N, G = cfg.ssm_state, cfg.ssm_groups
         H = Di // cfg.ssm_head_dim
         Q = min(cfg.ssm_chunk, S)
-        fl += 2 * S * D * (2 * Di + 2 * N + H)  # in proj
-        fl += 2 * S * Q * N  # intra-chunk scores (head-shared)
+        conv = Di + 2 * G * N
+        fl += 2 * S * D * (2 * Di + 2 * G * N + H)  # in proj
+        fl += 2 * S * Q * N * G  # intra-chunk scores (shared by a group)
         fl += 2 * 2 * S * Q * Di  # intra-chunk weighted values (+decay apply)
         fl += 2 * 2 * S * N * Di  # chunk states + inter-chunk outputs
         fl += 2 * S * Di * D  # out proj
-        pr += D * (2 * Di + 2 * N + H) + cfg.conv_width * (Di + 2 * N) + Di * D + 3 * H + Di
+        pr += (D * (2 * Di + 2 * G * N + H) + cfg.conv_width * conv
+               + conv * cfg.conv_bias + Di * D + 3 * H + Di)
     else:
         raise ValueError(kind)
     pr += 2 * D  # norms
@@ -169,6 +193,9 @@ def active_params(cfg: ModelConfig) -> int:
     """Active params per token (MoE top-k counting) — for MODEL_FLOPS=6*N*D."""
     n = total_params(cfg)
     if cfg.n_experts:
-        per_expert = 3 * cfg.d_model * cfg.moe_d_ff
-        n -= cfg.n_layers * (cfg.n_experts - cfg.moe_top_k) * per_expert
+        per_expert = _mlp_params(cfg, cfg.moe_d_ff)
+        n_moe = sum(k in ("moe", "moe_dense", "moe_only")
+                    for k in cfg.layer_kinds())
+        idle = cfg.n_experts_held * (1 - cfg.moe_top_k / cfg.n_experts)
+        n -= int(n_moe * idle * per_expert)
     return int(n)

@@ -21,7 +21,8 @@ from . import layers as L
 from .layers import Ctx
 from .sharding import constrain
 
-KINDS_WITH_KV = ("attn", "local_attn", "moe", "moe_dense", "dec_block")
+KINDS_WITH_KV = ("attn", "local_attn", "moe", "moe_dense", "dec_block",
+                 "attn_only")
 
 
 # ------------------------------------------------------------------- params --
@@ -56,6 +57,10 @@ def init_block(key, cfg: ModelConfig, kind: str) -> dict:
         p["mlp"] = L.init_mlp(ks[1], cfg)
     elif kind == "ssd":
         p["ssd"] = L.init_ssd(ks[0], cfg)
+    elif kind == "attn_only":
+        p["attn"] = L.init_attention(ks[0], cfg)
+    elif kind == "moe_only":
+        p["moe"] = L.init_moe(ks[0], cfg)
     else:
         raise ValueError(f"unknown block kind {kind}")
     return p
@@ -73,7 +78,8 @@ def _sp_scatter(h):
 
 
 def apply_block(p, cfg: ModelConfig, kind: str, x, ctx: Ctx, cache):
-    """Pre-norm block; returns (x, new_cache, aux_loss).
+    """Pre-norm block; returns (x, new_cache, stats), ``stats`` as
+    ``layers.no_stats`` lays them out (the MoE's aux loss and counters).
 
     Sequence parallelism, Megatron-SP style: the residual stream (and thus the
     scan carry the backward pass saves per layer) stays sequence-sharded at all
@@ -90,7 +96,7 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, ctx: Ctx, cache):
         with jax.named_scope(scope):
             return fn(*args, **kw)
 
-    aux = jnp.zeros((), jnp.float32)
+    stats = L.no_stats()
     if kind in ("attn", "local_attn", "moe", "moe_dense"):
         window = cfg.window if kind == "local_attn" else None
         h, cache = sub("attn", L.attention_block, p["attn"], cfg,
@@ -98,7 +104,7 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, ctx: Ctx, cache):
         x = x + _sp_scatter(h)
         hin = norm_in("ln2")
         if kind in ("moe", "moe_dense"):
-            y, aux = sub("moe", L.moe_ffn, p["moe"], cfg, hin)
+            y, stats = sub("moe", L.moe_ffn, p["moe"], cfg, hin)
             if kind == "moe_dense":
                 y = y + sub("mlp", L.mlp, p["mlp"], cfg, hin)
         else:
@@ -129,10 +135,17 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, ctx: Ctx, cache):
     elif kind == "ssd":
         h, cache = L.ssd_block(p["ssd"], cfg, norm_in("ln1"), ctx, cache)
         x = x + _sp_scatter(h)
+    elif kind == "attn_only":
+        h, cache = sub("attn", L.attention_block, p["attn"], cfg,
+                       norm_in("ln1"), ctx, cache)
+        x = x + _sp_scatter(h)
+    elif kind == "moe_only":
+        y, stats = sub("moe", L.moe_ffn, p["moe"], cfg, norm_in("ln1"))
+        x = x + _sp_scatter(y)
     else:
         raise ValueError(kind)
     x = constrain(x, ("batch", "seq", None))
-    return x, cache, aux
+    return x, cache, stats
 
 
 def init_cross_cache(cfg: ModelConfig, batch: int):
@@ -144,7 +157,7 @@ def init_cross_cache(cfg: ModelConfig, batch: int):
 
 
 def init_block_cache(cfg: ModelConfig, kind: str, batch: int, length: int):
-    if kind in ("attn", "moe", "moe_dense"):
+    if kind in ("attn", "moe", "moe_dense", "attn_only"):
         return L.init_kv_cache(cfg, batch, length)
     if kind == "dec_block":
         return {"self": L.init_kv_cache(cfg, batch, length),
@@ -199,9 +212,9 @@ def init_stack_cache(cfg: ModelConfig, n_layers: int, pattern, batch, length):
 
 def apply_stack(p, cfg: ModelConfig, n_layers: int, pattern, x, ctx: Ctx, cache):
     """Scan over pattern repetitions; unrolled remainder.  Returns
-    (x, new_cache, aux_sum)."""
+    (x, new_cache, stats) with the blocks' stats added up."""
     lay = StackLayout.of(n_layers, pattern)
-    aux_total = jnp.zeros((), jnp.float32)
+    stats = L.no_stats()
 
     if lay.full_reps:
         # NOTE(§Perf, refuted hypothesis): nesting a per-block jax.checkpoint
@@ -209,35 +222,36 @@ def apply_stack(p, cfg: ModelConfig, n_layers: int, pattern, x, ctx: Ctx, cache)
         # llama-90b/train_4k) and cost +15% recompute FLOPs — the peak is held
         # by matmul-dtype-legalization copies, not multi-block liveness.
         def body(carry, xs):
-            h, aux_acc = carry
+            h, acc = carry
             params_t, cache_t = xs
             new_caches = []
             for i, kind in enumerate(lay.pattern):
-                h, c, aux = apply_block(params_t[i], cfg, kind, h, ctx,
-                                        cache_t[i] if cache is not None else None)
+                h, c, st = apply_block(params_t[i], cfg, kind, h, ctx,
+                                       cache_t[i] if cache is not None else None)
+                acc = L.add_stats(acc, st)
                 new_caches.append(c if c is not None else {})
-            return (h, aux_acc + aux), tuple(new_caches)
+            return (h, acc), tuple(new_caches)
 
         if cfg.remat and ctx.mode == "train":
             body = jax.checkpoint(body,
                                   policy=jax.checkpoint_policies.nothing_saveable)
         cache_groups = (tuple(cache["groups"]) if cache is not None
                         else tuple({} for _ in lay.pattern))
-        (x, aux_total), new_groups = jax.lax.scan(
-            body, (x, aux_total), (tuple(p["groups"]), cache_groups))
+        (x, stats), new_groups = jax.lax.scan(
+            body, (x, stats), (tuple(p["groups"]), cache_groups))
         new_groups = list(new_groups)
     else:
         new_groups = []
 
     new_rem = []
     for i, kind in enumerate(lay.remainder):
-        x, c, aux = apply_block(p["rem"][i], cfg, kind, x, ctx,
-                                cache["rem"][i] if cache is not None else None)
-        aux_total = aux_total + aux
+        x, c, st = apply_block(p["rem"][i], cfg, kind, x, ctx,
+                               cache["rem"][i] if cache is not None else None)
+        stats = L.add_stats(stats, st)
         new_rem.append(c if c is not None else {})
     new_cache = ({"groups": new_groups, "rem": new_rem}
                  if cache is not None else None)
-    return x, new_cache, aux_total
+    return x, new_cache, stats
 
 
 # ---------------------------------------------------------------- full model --
@@ -284,15 +298,15 @@ def encode_memory(params, cfg: ModelConfig, memory):
 
 def forward(params, cfg: ModelConfig, tokens, ctx: Ctx, cache=None,
             memory=None):
-    """tokens (B, S) -> (hidden (B, S, D), new_cache, aux)."""
+    """tokens (B, S) -> (hidden (B, S, D), new_cache, stats)."""
     if memory is not None:
         ctx = dataclasses.replace(ctx, memory=encode_memory(params, cfg, memory))
     x = embed_tokens(params, cfg, tokens)
     x = constrain(x, ("batch", "seq", None))
-    x, new_cache, aux = apply_stack(params["stack"], cfg, cfg.n_layers,
-                                    cfg.pattern, x, ctx, cache)
+    x, new_cache, stats = apply_stack(params["stack"], cfg, cfg.n_layers,
+                                      cfg.pattern, x, ctx, cache)
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return x, new_cache, aux
+    return x, new_cache, stats
 
 
 def init_cache(cfg: ModelConfig, batch: int, length: int):

@@ -81,23 +81,24 @@ def _stage_apply(stage_groups, valid, cfg: ModelConfig, x, ctx: Ctx):
     """Scan this stage's Gmax group slots over the residual stream."""
 
     def body(carry, xs):
-        h, aux_acc = carry
+        h, acc = carry
         params_g, valid_g = xs
-        h2 = h
-        aux = jnp.zeros((), jnp.float32)
+        h2, stats = h, L.no_stats()
         for i, kind in enumerate(cfg.pattern):
-            h2, _, a = T.apply_block(params_g[i], cfg, kind, h2, ctx, None)
-            aux = aux + a
+            h2, _, st = T.apply_block(params_g[i], cfg, kind, h2, ctx, None)
+            stats = L.add_stats(stats, st)
         h = jnp.where(valid_g, h2, h)
-        aux_acc = aux_acc + jnp.where(valid_g, aux, 0.0)
-        return (h, aux_acc), None
+        acc = L.add_stats(acc, jax.tree.map(
+            lambda v: jnp.where(valid_g, v, jnp.zeros_like(v)), stats))
+        return (h, acc), None
 
+    # the remat unit is one whole group: every pattern position of it
     if cfg.remat:
         body = jax.checkpoint(body,
                               policy=jax.checkpoint_policies.nothing_saveable)
-    (h, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               (stage_groups, valid))
-    return h, aux
+    (h, stats), _ = jax.lax.scan(body, (x, L.no_stats()),
+                                 (stage_groups, valid))
+    return h, stats
 
 
 def pipelined_apply(groups_stacked, valid, h_mb, *, cfg: ModelConfig, K: int,
@@ -107,7 +108,8 @@ def pipelined_apply(groups_stacked, valid, h_mb, *, cfg: ModelConfig, K: int,
     groups_stacked: per-pattern-position trees, leading (1, Gmax, ...) local
     (stage-sharded); h_mb: (M, mb_local, S, D) microbatched embeddings
     (replicated over 'stage').  Returns ((M, mb, S, D) outputs — valid on the
-    LAST stage's shard — and the stage-local aux-loss sum)."""
+    LAST stage's shard — and the stage-local stats, ``layers.no_stats``'s
+    keys, each with a leading stage axis of 1)."""
     stage = jax.lax.axis_index("stage")
     M = n_micro
     n_ticks = M + K - 1
@@ -123,17 +125,23 @@ def pipelined_apply(groups_stacked, valid, h_mb, *, cfg: ModelConfig, K: int,
 
     def bubble_fn(xi):
         with jax.named_scope("bubble"):
-            return xi, jnp.zeros((), jnp.float32)
+            return xi, L.no_stats()
 
     def tick(carry, t):
-        received, outs, aux_acc = carry
+        received, outs, acc = carry
         inject = h_mb[jnp.clip(t, 0, M - 1)]
         x_in = jnp.where(stage == 0, inject, received)
         # bubble skipping: stage i only has real work for ticks i <= t < i+M;
         # lax.cond (real XLA conditional — not vmapped into a select here)
         # skips the fill/drain garbage compute entirely
-        active = (t >= stage) & (t - stage < M)
-        y, aux = jax.lax.cond(active, stage_fn, bubble_fn, x_in)
+        if K == 1:
+            # every tick of a one-stage chain is active; without the cond the
+            # stage's weights stay loop constants of the tick scan, where a
+            # cond's residuals are stacked once a tick
+            y, stats = stage_fn(x_in)
+        else:
+            active = (t >= stage) & (t - stage < M)
+            y, stats = jax.lax.cond(active, stage_fn, bubble_fn, x_in)
         # the last stage collects microbatch t - (K - 1)
         oidx = jnp.clip(t - (K - 1), 0, M - 1)
         take = t >= K - 1
@@ -144,19 +152,21 @@ def pipelined_apply(groups_stacked, valid, h_mb, *, cfg: ModelConfig, K: int,
         with jax.named_scope("ppermute"):
             nxt = jax.lax.ppermute(y, "stage",
                                    [(i, (i + 1) % K) for i in range(K)])
-        return (nxt, outs, aux_acc + aux), None
+        return (nxt, outs, L.add_stats(acc, stats)), None
 
     with jax.named_scope("tick"):
-        (_, outs, aux), _ = jax.lax.scan(
+        (_, outs, stats), _ = jax.lax.scan(
             tick, (jnp.zeros_like(h_mb[0]), jnp.zeros_like(h_mb),
-                   jnp.zeros((), jnp.float32)),
+                   L.no_stats()),
             jnp.arange(n_ticks))
-    return outs, aux[None]
+    return outs, jax.tree.map(lambda v: v[None], stats)
 
 
 def pipeline_forward(params, batch, cfg: ModelConfig, mesh: Mesh,
                      plan: PipelinePlan, n_micro: int):
-    """Embed -> pipelined blocks -> final hidden states (B, S, D) + aux."""
+    """Embed -> pipelined blocks -> final hidden states (B, S, D) and the
+    step's stats: the aux loss averaged over ticks, the MoE counters summed
+    over stages (``moe_max_rows``: the largest)."""
     tokens = batch["tokens"]
     B, S = tokens.shape
     assert B % n_micro == 0
@@ -175,7 +185,7 @@ def pipeline_forward(params, batch, cfg: ModelConfig, mesh: Mesh,
         out_specs=(P("stage", "data"), P("stage")),
         check_vma=False,
     )
-    outs, aux = fn(groups_stacked, valid, h_mb)
+    outs, stats = fn(groups_stacked, valid, h_mb)
     # out dim0 is stage-major (K * M); the last stage's block holds the model
     # output microbatches
     with jax.named_scope("head"):
@@ -184,23 +194,32 @@ def pipeline_forward(params, batch, cfg: ModelConfig, mesh: Mesh,
         hidden = L.rmsnorm(hidden, params["final_norm"], cfg.norm_eps)
     # aux averaged over ticks (bubble ticks process pass-through garbage; the
     # valid-slot masking keeps their contribution bounded)
-    return hidden, jnp.sum(aux) / (n_micro + plan.K - 1)
+    return hidden, {"aux": jnp.sum(stats["aux"]) / (n_micro + plan.K - 1),
+                    "moe_rows": jnp.sum(stats["moe_rows"]),
+                    "moe_max_rows": jnp.max(stats["moe_max_rows"])}
 
 
 def make_pipeline_train_step(cfg: ModelConfig, mesh: Mesh, plan: PipelinePlan,
                              n_micro: int, opt):
     def loss_fn(params, batch):
-        hidden, aux = pipeline_forward(params, batch, cfg, mesh, plan, n_micro)
+        hidden, stats = pipeline_forward(params, batch, cfg, mesh, plan,
+                                         n_micro)
         with jax.named_scope("head"):
             head_w = T.head_matrix(params, cfg).astype(hidden.dtype)
             nll = chunked_xent(hidden, head_w, batch["targets"], cfg)
-        return nll + 0.01 * aux, nll
+        return nll + 0.01 * stats["aux"], (nll, stats)
 
     def train_step(params, opt_state, batch):
-        (loss, nll), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, batch)
+        """Returns the new state and metrics: the loss, its ``nll`` part,
+        and the step's MoE counters (``moe_rows``: rows the held experts
+        computed, ``moe_max_rows``: the largest held expert's rows in one
+        layer and microbatch; 0 without MoE)."""
+        (loss, (nll, stats)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, batch)
         with jax.named_scope("optimizer"):
             params, opt_state = opt.update(grads, opt_state, params)
-        return params, opt_state, {"loss": loss, "nll": nll}
+        return params, opt_state, {"loss": loss, "nll": nll,
+                                   "moe_rows": stats["moe_rows"],
+                                   "moe_max_rows": stats["moe_max_rows"]}
 
     return train_step

@@ -84,10 +84,14 @@ def check_pipeline(cfg, plan, mesh, *, batch: int, seq: int, n_micro: int,
 
 def main(arch: str = "qwen3-14b") -> None:
     from ..configs import ARCHS
+    from ..configs.registry import PERIODS
     from .pipeline import make_pipeline_mesh
     from .planner import PipelinePlan
 
     cfg = ARCHS[arch].reduced()
+    if arch in PERIODS:  # two whole periods, so that each stage holds one
+        cfg = ARCHS[arch].reduced(pattern=PERIODS[arch],
+                                  n_layers=2 * len(PERIODS[arch]))
     R = cfg.n_layers // len(cfg.pattern)
     # balanced K=2 segments over R groups (the planner itself is tested in
     # tests/test_msl.py::test_plan_pipeline)

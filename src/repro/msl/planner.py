@@ -66,7 +66,8 @@ def plan_pipeline(cfg: ModelConfig, *, seq_len: int, microbatch: int,
                   mode: str = TR, solver: str = "bcd") -> PipelinePlan:
     """Choose K and the per-stage group ranges minimizing the paper objective
     on the pod-level topology.  `microbatch` plays the paper's batch-size b
-    role (smashed data = microbatch x activation bytes)."""
+    role (smashed data = microbatch x activation bytes).  A model of one
+    pattern group (one period of a long pattern) admits only K = 1."""
     prof = group_profile(cfg, seq_len, "train" if mode == TR else "prefill")
     net = tpu_pod_topology(n_groups=n_groups_mesh,
                            chips_per_group=chips_per_group)

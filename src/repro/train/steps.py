@@ -70,11 +70,11 @@ def loss_fn(params, cfg: ModelConfig, batch, mode: str = "train"):
     B, S = tokens.shape
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     ctx = Ctx(mode=mode, positions=pos)
-    hidden, _, aux = T.forward(params, cfg, tokens, ctx,
-                               memory=batch.get("memory"))
+    hidden, _, stats = T.forward(params, cfg, tokens, ctx,
+                                 memory=batch.get("memory"))
     head_w = T.head_matrix(params, cfg).astype(hidden.dtype)
     nll = chunked_xent(hidden, head_w, targets, cfg)
-    return nll + AUX_LOSS_COEF * aux, {"nll": nll, "aux": aux}
+    return nll + AUX_LOSS_COEF * stats["aux"], {"nll": nll, **stats}
 
 
 def make_train_step(cfg: ModelConfig, opt: Optimizer, max_grad_norm: float = 1.0):

@@ -42,23 +42,6 @@ def test_flash_attention(B, Sq, Sk, Hq, Hkv, hd, causal, window, softcap, dtype)
         atol=TOL[dtype], rtol=TOL[dtype])
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("E,C,D,F,act", [
-    (4, 64, 96, 80, "none"),
-    (2, 128, 256, 128, "silu"),
-    (8, 40, 72, 200, "gelu"),  # ragged, padding exercised
-])
-def test_expert_matmul(E, C, D, F, act, dtype):
-    x = _rand((E, C, D), dtype)
-    w = _rand((E, D, F), dtype) * 0.1
-    out = ops.expert_matmul(x, w, activation=act, block_c=32, block_f=64,
-                            block_d=64)
-    expect = ref.reference_expert_matmul(x, w, activation=act)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(expect, np.float32),
-        atol=8 * TOL[dtype], rtol=8 * TOL[dtype])
-
-
 @pytest.mark.parametrize("B,S,W,bs,bw", [
     (2, 64, 128, 16, 64),
     (1, 100, 48, 32, 32),  # ragged
@@ -83,24 +66,35 @@ def test_rglru_scan(B, S, W, bs, bw):
 # every position); the bound is 2e-2.  The same kernels with float32 dot
 # operands agree with the oracle to 2e-5.
 SSD_TOL = 2e-2
-SSD_SHAPES = [  # B, S, H, P, N, Q
-    (1, 256, 2, 64, 128, 128),   # one head group, two chunks
-    (1, 256, 4, 32, 128, 128),   # four heads a group
-    (1, 256, 2, 128, 128, 128),  # one head a group
-    (2, 512, 4, 64, 128, 256),   # the cell's Q, P, N; two head groups
+SSD_SHAPES = [  # B, S, H, P, N, Q, G (SSM groups; B and C (B, S, G, N))
+    pytest.param(1, 256, 2, 64, 128, 128, 1,   # one head group, two chunks
+                 id="1-256-2-64-128-128"),
+    pytest.param(1, 256, 4, 32, 128, 128, 1,   # four heads a group
+                 id="1-256-4-32-128-128"),
+    pytest.param(1, 256, 2, 128, 128, 128, 1,  # one head a group
+                 id="1-256-2-128-128-128"),
+    pytest.param(2, 512, 4, 64, 128, 256, 1,   # mamba2-370m's Q, P, N
+                 id="2-512-4-64-128-256"),
+    # Nemotron-H's Q, P, N: SSM groups of 8 heads of 64, one 512-lane step
+    # each; two such steps, then all 8 groups of the published 64 heads
+    pytest.param(1, 256, 16, 64, 128, 128, 2, id="1-256-16-64-128-128-g2"),
+    pytest.param(1, 256, 64, 64, 128, 128, 8, id="1-256-64-64-128-128-g8"),
+    # four heads a group of two 128-lane head groups: C B^T per grid step
+    pytest.param(1, 256, 8, 64, 128, 128, 2, id="1-256-8-64-128-128-g2"),
 ]
 
 
-def _ssd_args(B, S, H, P, N):
+def _ssd_args(B, S, H, P, N, G=1):
     def bf16_exact(a):
         return jnp.asarray(a, jnp.float32).astype(jnp.bfloat16).astype(
             jnp.float32)
 
+    bc = (B, S, N) if G == 1 else (B, S, G, N)
     return (bf16_exact(RNG.standard_normal((B, S, H, P))),
             jnp.asarray(RNG.uniform(0.01, 0.2, (B, S, H)), jnp.float32),
             -jnp.asarray(RNG.uniform(0.5, 4.0, (H,)), jnp.float32),
-            bf16_exact(0.3 * RNG.standard_normal((B, S, N))),
-            bf16_exact(0.3 * RNG.standard_normal((B, S, N))),
+            bf16_exact(0.3 * RNG.standard_normal(bc)),
+            bf16_exact(0.3 * RNG.standard_normal(bc)),
             jnp.asarray(RNG.uniform(0.5, 1.5, (H,)), jnp.float32),
             jnp.asarray(0.1 * RNG.standard_normal((B, H, P, N)), jnp.float32))
 
@@ -130,11 +124,11 @@ def _assert_close(got, want, name):
     assert err <= SSD_TOL * scale, (name, err, scale)
 
 
-@pytest.mark.parametrize("B,S,H,P,N,Q", SSD_SHAPES)
-def test_ssd_intra_chunk(B, S, H, P, N, Q):
+@pytest.mark.parametrize("B,S,H,P,N,Q,G", SSD_SHAPES)
+def test_ssd_intra_chunk(B, S, H, P, N, Q, G):
     """Forward of the kernel path (y and h_last) against the oracle; without
     an incoming state the intra-chunk and chunk-state terms alone."""
-    x, dt, A, Bm, Cm, D, h0 = _ssd_args(B, S, H, P, N)
+    x, dt, A, Bm, Cm, D, h0 = _ssd_args(B, S, H, P, N, G)
     for init in (None, h0):
         y_k, h_k = _ssd_kernel_path(Q)(x, dt, A, Bm, Cm, D, init)
         y_m, h_m = _ssd_oracle(Q)(x, dt, A, Bm, Cm, D, init)
@@ -142,11 +136,11 @@ def test_ssd_intra_chunk(B, S, H, P, N, Q):
         _assert_close(h_k, h_m, "h_last")
 
 
-@pytest.mark.parametrize("B,S,H,P,N,Q", SSD_SHAPES)
-def test_ssd_vjp_matches_model_layer(B, S, H, P, N, Q):
+@pytest.mark.parametrize("B,S,H,P,N,Q,G", SSD_SHAPES)
+def test_ssd_vjp_matches_model_layer(B, S, H, P, N, Q, G):
     """jax.vjp of the kernel path: y, h_last and the cotangents of x, dt, A,
     B, C, D and h0 against the oracle's."""
-    args = _ssd_args(B, S, H, P, N)
+    args = _ssd_args(B, S, H, P, N, G)
     (y_k, h_k), vjp_k = jax.vjp(_ssd_kernel_path(Q), *args)
     (y_m, h_m), vjp_m = jax.vjp(_ssd_oracle(Q), *args)
     cts = (jnp.asarray(RNG.standard_normal(y_m.shape), jnp.float32),

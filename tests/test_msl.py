@@ -46,7 +46,8 @@ def test_group_profile_conserves_totals():
 
 
 @pytest.mark.slow  # subprocess shard_map pipeline run (~1 min per arch)
-@pytest.mark.parametrize("arch", ["qwen3-14b", "mamba2-370m"])
+@pytest.mark.parametrize("arch", ["qwen3-14b", "mamba2-370m",
+                                  "nemotron3-nano-30b-a3b"])
 def test_pipeline_runtime_equivalence(arch):
     """Pipelined forward == sequential forward; pipelined train step runs."""
     env = dict(os.environ)

@@ -16,6 +16,7 @@ EXPECTED_PARAMS_B = {  # nameplate sanity bands
     "recurrentgemma-9b": (8.3, 10.5),
     "whisper-small": (0.15, 0.4),
     "mamba2-370m": (0.3, 0.45),
+    "nemotron3-nano-30b-a3b": (31, 32.2),   # published 31.6B
 }
 
 
@@ -74,3 +75,36 @@ def test_planner_runs_on_arch_profiles(arch):
     heur = bcd_solve(net, prof, req, K, cands)
     assert opt.feasible and heur.feasible
     assert heur.latency_s <= 1.5 * opt.latency_s + 1e-9
+
+
+def test_expert_share_and_single_mixer_blocks_are_priced():
+    """A chain node of Nemotron-H (one period E M E M E M *, 8 of 128
+    experts held, a 16,384-id vocabulary slice) prices its attention-only
+    and MoE-only blocks and its held experts: 528.1 M parameters, the held
+    experts at their expected load, and a plan of its one group on K = 1."""
+    import dataclasses
+
+    from repro.configs.nemotron3_nano_30b_a3b import PERIOD
+    from repro.msl import plan_pipeline
+
+    full = ARCHS["nemotron3-nano-30b-a3b"]
+    cfg = dataclasses.replace(full, n_layers=len(PERIOD), pattern=PERIOD,
+                              vocab_size=16384, moe_experts_held=8)
+    assert total_params(cfg) == 528_111_936
+    prof = model_profile(cfg, seq_len=4096, mode="train")
+    rows = {l.name: l for l in prof.layers}
+    D, S = 2688, 4096
+    held = 6 * 8 / 128 * 2 * 2 * S * D * 1856
+    moe = 2 * S * D * 128 + held + 2 * 2 * S * D * 3712
+    assert rows["moe_only0"].flops_fw == pytest.approx(moe)
+    attn = 2 * S * D * (32 + 4) * 128 + 2 * S * 4096 * D \
+        + 2 * 2 * S * S / 2 * 32 * 128
+    assert rows["attn_only6"].flops_fw == pytest.approx(attn)
+    # the uncut layer holds every expert and runs top-6 of them a token
+    whole = dataclasses.replace(cfg, moe_experts_held=0)
+    per_expert = 2 * D * 1856
+    assert total_params(whole) - total_params(cfg) == 3 * 120 * per_expert
+    assert active_params(whole) < total_params(whole)
+    plan = plan_pipeline(cfg, seq_len=S, microbatch=2, candidate_K=(1,))
+    assert plan.K == 1 and plan.segments == [(1, 1)] and plan.n_groups == 1
+    assert plan.predicted_latency_s > 0

@@ -125,9 +125,9 @@ def test_stage_apply_group_compiles_full_width(one_chip):
 
     def loss(g, x):
         pos = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32), (mb, seq))
-        h, aux = _stage_apply(g, jnp.ones((1,), bool), cfg, x,
-                              Ctx(mode="train", positions=pos))
-        return jnp.sum(h.astype(jnp.float32)) + aux
+        h, stats = _stage_apply(g, jnp.ones((1,), bool), cfg, x,
+                                Ctx(mode="train", positions=pos))
+        return jnp.sum(h.astype(jnp.float32)) + stats["aux"]
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         group, x).compile()
@@ -136,3 +136,44 @@ def test_stage_apply_group_compiles_full_width(one_chip):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+
+
+def test_nemotron_stage_group_compiles_at_cell_widths(one_chip):
+    """Forward and backward of the Nemotron-H period (E M E M E M *) at
+    published widths with 8 of 128 experts held, as the benchmark cell's
+    stage runs it (microbatch 1, seq 4096).  Lowered for the TPU, the SSD
+    scan runs as the grouped kernel pair (8 SSM groups) and the held
+    experts as grouped-matmul kernels."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.nemotron3_nano_30b_a3b import PERIOD
+    from repro.models import transformer as T
+    from repro.models.layers import Ctx
+    from repro.msl.pipeline import _stage_apply
+
+    cfg = dataclasses.replace(get_config("nemotron3-nano-30b-a3b"),
+                              n_layers=len(PERIOD), pattern=PERIOD,
+                              vocab_size=16384, moe_experts_held=8)
+    params = jax.eval_shape(lambda k: T.init_params(k, cfg),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    group = tuple(
+        jax.tree.map(lambda p: _spec((1,) + p.shape[1:], p.dtype, one_chip),
+                     g) for g in params["stack"]["groups"])
+    mb, seq = 1, 4096
+    x = _spec((mb, seq, cfg.d_model), jnp.bfloat16, one_chip)
+
+    def loss(g, x):
+        pos = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32), (mb, seq))
+        h, stats = _stage_apply(g, jnp.ones((1,), bool), cfg, x,
+                                Ctx(mode="train", positions=pos))
+        return jnp.sum(h.astype(jnp.float32)), stats
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        group, x).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 * 1024**3
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+    assert "gmm" in text and "tgmm" in text
