@@ -9,6 +9,9 @@ CPU; Mosaic lowering on real TPUs.  The SSD scan's forward and backward
 kernels (ssd.py) are the train step's SSD path on a TPU where the shapes tile
 (``models.layers`` chooses by the platform lowered for and by shape); its
 oracle, and the path everywhere else, is ``models.layers._ssd_chunked``.
+Likewise the flash-attention pair (flash_attention.py) is the training
+path's attention on a TPU (``models.layers._train_attention``); its oracle,
+and the path everywhere else, is ``models.layers.blocked_attention``.
 """
 from . import ops, ref
 from .flash_attention import flash_attention

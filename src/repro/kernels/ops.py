@@ -1,5 +1,6 @@
 """Public wrappers around the Pallas kernels: the composed SSD scan that
-takes the model layer's arguments, beside the kernels' own entry points.
+takes the model layer's arguments, beside the kernels' own entry points
+(``flash_attention``, differentiable, is the attention kernel pair's).
 ``interpret=True`` runs a kernel in the Pallas interpreter (the CPU tests);
 otherwise it lowers through Mosaic for the TPU."""
 from __future__ import annotations

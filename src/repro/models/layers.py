@@ -6,7 +6,9 @@ Conventions:
   * activations x: (B, S, D); positions: (B, S) int32.
   * attention is *blocked* over query chunks (lax.scan) so compiled memory stays
     bounded at 32k+ sequence lengths — this pure-jnp path is also the oracle for
-    the Pallas flash-attention kernel.
+    the Pallas flash-attention kernel pair (``kernels/flash_attention.py``),
+    which the training path runs instead when lowered for a TPU where the
+    shapes tile (``_train_attention``); the CPU, prefill and decode keep it.
   * every block returns (y, new_cache); cache=None outside decode/prefill.
 """
 from __future__ import annotations
@@ -22,6 +24,7 @@ from jax.sharding import PartitionSpec
 
 from ..configs.base import ModelConfig
 from ..kernels import ops as ssd_ops
+from ..kernels.flash_attention import flash_attention, flash_tiles
 from ..kernels.ssd import ssd_tiles
 from .sharding import active_rules, constrain_first
 
@@ -49,7 +52,13 @@ _O_SPECS = [("batch", None, "tp", None),  # (B, qc, H, hd): heads
 class Ctx:
     """Per-call context threaded through blocks (a pytree: arrays are leaves,
     mode flags are static metadata — so Ctx can cross jit/checkpoint/shard_map
-    boundaries)."""
+    boundaries).
+
+    Every Ctx of a call without a cache (training, the encoder, the
+    simulator's stage) has positions ``arange(S)`` in each row, so a key's
+    index in the sequence is its position: the flash-attention kernels of
+    the training path mask by block index (``_train_attention``), which
+    equals the blocked oracle's mask by position only under that rule."""
 
     mode: str  # "train" | "prefill" | "decode"
     positions: jnp.ndarray  # (B, S) int32 absolute positions
@@ -242,6 +251,35 @@ def blocked_attention(cfg: ModelConfig, q, k, v, q_positions, kv_positions,
     return out[:, :Sq]
 
 
+# query and key blocks of the flash-attention kernels on the training path
+FLASH_BLOCKS = (512, 512)
+
+
+def _train_attention(cfg: ModelConfig, q, k, v, positions, causal, window):
+    """Self-attention of a call without a cache.  Lowered for a TPU, where
+    the shapes tile and no mesh rules are active (a Mosaic kernel is not
+    partitioned by GSPMD, so heads sharded over TP keep the blocked path),
+    it runs the flash-attention kernel pair, which masks by index: the
+    positions are ``arange(S)`` (``Ctx``).  Everywhere else, and as its
+    oracle, ``blocked_attention``."""
+    def oracle(q, k, v, positions):
+        return blocked_attention(cfg, q, k, v, positions, positions, causal,
+                                 window)
+
+    S, hd = q.shape[1], q.shape[3]
+    if active_rules() is not None or not flash_tiles(S, S, hd, *FLASH_BLOCKS):
+        return oracle(q, k, v, positions)
+
+    def tpu(q, k, v, positions):
+        return flash_attention(
+            q, k, v, causal=causal, window=window,
+            softcap=cfg.attn_softcap or None, block_q=FLASH_BLOCKS[0],
+            block_k=FLASH_BLOCKS[1], interpret=False)
+
+    return jax.lax.platform_dependent(q, k, v, positions, tpu=tpu,
+                                      default=oracle)
+
+
 def _decode_attention(cfg, q, k, v, q_positions, kv_positions, window=None):
     """Single-token decode: q (B, 1, Hq, hd) against the full cache.
 
@@ -337,8 +375,8 @@ def attention_block(p, cfg: ModelConfig, x, ctx: Ctx, cache,
                                     ctx.positions, ctx.positions, True, window)
     else:  # training: no cache
         q, k, v = _project_qkv(p, cfg, x, x, ctx.positions, ctx.positions)
-        out = blocked_attention(cfg, q, k, v, ctx.positions, ctx.positions,
-                                ctx.causal, window)
+        out = _train_attention(cfg, q, k, v, ctx.positions, ctx.causal,
+                               window)
         new_cache = None
     B, S = x.shape[:2]
     out = out.reshape(B, S, -1) @ p["wo"].astype(cdt(cfg))

@@ -105,6 +105,32 @@ def test_ssd_scan_kernels_compile_at_cell_shapes(one_chip):
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
 
 
+def test_flash_attention_kernels_compile_at_cell_shapes(one_chip):
+    """The flash-attention forward and backward kernels alone, at the shapes
+    the Nemotron-H attention layer gives them in the benchmark cell:
+    microbatch 1, seq 4096, 32 query heads and 2 kv heads of 128, bf16,
+    the training path's blocks."""
+    from repro.kernels import ops
+    from repro.models.layers import FLASH_BLOCKS
+
+    B, S, Hq, Hkv, hd = 1, 4096, 32, 2, 128
+
+    def loss(q, k, v):
+        o = ops.flash_attention(q, k, v, causal=True,
+                                block_q=FLASH_BLOCKS[0],
+                                block_k=FLASH_BLOCKS[1], interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _spec((B, S, Hq, hd), bf16, one_chip),
+        _spec((B, S, Hkv, hd), bf16, one_chip),
+        _spec((B, S, Hkv, hd), bf16, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+
+
 def test_stage_apply_group_compiles_full_width(one_chip):
     """Forward and backward of one mamba2-370m group at published width, as
     a pipeline stage runs it (microbatch 4, seq 2048).  Lowered for the TPU,
@@ -142,8 +168,9 @@ def test_nemotron_stage_group_compiles_at_cell_widths(one_chip):
     """Forward and backward of the Nemotron-H period (E M E M E M *) at
     published widths with 8 of 128 experts held, as the benchmark cell's
     stage runs it (microbatch 1, seq 4096).  Lowered for the TPU, the SSD
-    scan runs as the grouped kernel pair (8 SSM groups) and the held
-    experts as grouped-matmul kernels."""
+    scan runs as the grouped kernel pair (8 SSM groups), the held experts as
+    grouped-matmul kernels and attention as the flash kernel pair (its
+    backward, and the forward the backward recomputes)."""
     import dataclasses
 
     from repro.configs import get_config
@@ -177,3 +204,4 @@ def test_nemotron_stage_group_compiles_at_cell_widths(one_chip):
     assert "tpu_custom_call" in text
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
     assert "gmm" in text and "tgmm" in text
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
